@@ -73,12 +73,13 @@ def _mix_event_costs(harness, plan, batcher, state):
         return None
     fn = entry.build(*args)
     costs = hlo_analysis.analyze_hlo(fn.lower(*args).compile().as_text())
+    # entry points donate the state: each call feeds the previous output
     out = fn(*args)
     jax.block_until_ready(out[0].params)           # compile + warm
     reps = 4
     t0 = time.time()
     for _ in range(reps):
-        out = fn(*args)
+        out = fn(out[0], *args[1:])
     jax.block_until_ready(out[0].params)
     return (time.time() - t0) / reps, costs
 
@@ -101,7 +102,8 @@ def bench_policy(cfg, policy: str, slots: int, *, seq_len: int,
     harness = TrainHarness(cfg, mll, st, gate_mode=plan.gate_mode, mesh=mesh)
 
     def full_pass():
-        state = init_train_state(stacked, cfg=mll)
+        # a fresh copy per pass: the harness donates the state it is given
+        state = init_train_state(jax.tree.map(jnp.copy, stacked), cfg=mll)
         if mesh is not None:
             state = shard_train_state(state, mesh, network.num_workers)
         rng = np.random.default_rng(0)

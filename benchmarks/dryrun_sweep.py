@@ -42,7 +42,10 @@ def run_combo(arch, shape, *, multipod=False, phase="dynamic",
            "--out", path, *extra]
     if multipod:
         cmd.append("--multipod")
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    # the dry-run compiles for 512 forced HOST devices: pin the child to the
+    # CPU so it never claims an attached accelerator
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")
     t0 = time.time()
     p = subprocess.run(cmd, env=env, capture_output=True, text=True,
                        timeout=timeout)

@@ -35,6 +35,7 @@ def run_variant(arch: str, shape: str, tag: str, kwargs: dict,
     """)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    env["JAX_PLATFORMS"] = "cpu"    # host devices only; never claim a chip
     p = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=timeout)
     if p.returncode != 0:

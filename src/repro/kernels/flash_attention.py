@@ -1,5 +1,15 @@
 """Blocked flash attention (Pallas, TPU target): forward AND backward.
 
+Layout: the public wrappers take token-major (B, T, H, hd) arrays and move
+them head-major, (B, H, T, hd), before the kernels run.  Every block then
+ends in a (rows, head_dim) tile of the array it reads — the TPU compiler
+requires the last two block dims to be multiples of (8, 128) or equal to
+the array's own dims, which a (block_q, 1 head) slice of (B, T, H, hd)
+never is.  The per-row softmax statistics (logsumexp ``lse`` and the
+backward's ``delta``) live as (B, H, T, 1) columns with (1, 1, block_q, 1)
+blocks, so kernels read and write them as (block_q, 1) tiles without a
+lane/sublane transpose.
+
 Forward tiling: grid = (batch, q_heads, T/block_q, S/block_kv); the kv axis
 is the minormost ("arbitrary") grid dimension, accumulating the online
 softmax in VMEM scratch (running max m, normalizer l, weighted output acc)
@@ -23,21 +33,27 @@ softcap semantics.  The forward saves only `o` and the per-row logsumexp
   * dkv kernel — grid (B, Hkv, S/block_kv, T/block_q), q minormost
     arbitrary; dk/dv accumulate over the q-block axis in VMEM scratch and
     reduce over the q-head GQA group with a static in-kernel loop (the
-    whole group's q/do tiles arrive in one block).
+    whole group's (group, block_q, hd) q/do tiles arrive in one block).
 
 ``delta = rowsum(do * o)`` is precomputed in f32 by the wrapper (one fused
 elementwise-reduce pass; the FlashAttention "preprocess" step).  Fully
 masked tiles short-circuit in all three kernels via `pl.when` — the causal
 upper triangle and windows far in the past skip their matmuls entirely.
 
-VMEM budget per program instance (bf16 inputs, f32 scratch, hd padded):
+Decode (`flash_decode_paged`) reads the serving pool in its head-major
+(num_blocks, Hkv, block_size, hd) layout (`serve.kv_cache`): one
+(block_size, hd) tile per kv head and physical block.
+
+VMEM budget per program instance (bf16 inputs, f32 scratch, hd padded to
+128; the (rows, 1) columns occupy a full 128-lane tile row each):
   forward: q tile 128x128x2 = 32 KiB, k/v tiles 2x32 KiB,
-           acc/m/l f32 = 64+1 KiB
-  dq:      q/do/k/v tiles 4x32 KiB, dq acc f32 64 KiB, lse/delta 2x0.5 KiB
+           acc f32 64 KiB, m/l/lse columns 3x64 KiB
+  dq:      q/do/k/v tiles 4x32 KiB, dq acc f32 64 KiB, lse/delta 2x64 KiB
   dkv:     k/v tiles 2x32 KiB, q/do tiles 2x(group x 32 KiB),
-           dk/dv acc f32 2x64 KiB, lse/delta 2x(group x 0.5 KiB)
+           dk/dv acc f32 2x64 KiB, lse/delta 2x(group x 64 KiB)
   -> every variant stays well under the ~16 MiB v5e VMEM ceiling up to
-     GQA groups of 8 at head_dim 128; block sizes are tunable.
+     GQA groups of 8 at head_dim 128, double-buffered; block sizes are
+     tunable.
 """
 from __future__ import annotations
 
@@ -48,8 +64,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -64,10 +78,25 @@ def _pad_head_dim(hd: int) -> int:
     return _round_up(hd, 64)
 
 
-def _pad4(x: jnp.ndarray, t_pad: int, hd_pad: int) -> jnp.ndarray:
+def _head_major(x: jnp.ndarray, t_pad: int, hd_pad: int) -> jnp.ndarray:
+    """(B, T, H, hd) -> (B, H, T + t_pad, hd + hd_pad), zero-padded."""
+    x = jnp.swapaxes(x, 1, 2)
     if t_pad or hd_pad:
-        x = jnp.pad(x, ((0, 0), (0, t_pad), (0, 0), (0, hd_pad)))
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, t_pad), (0, hd_pad)))
     return x
+
+
+def _token_major(x: jnp.ndarray, t: int, hd: int) -> jnp.ndarray:
+    """Inverse of `_head_major`: (B, H, Tp, hd_p) -> (B, t, H, hd)."""
+    return jnp.swapaxes(x[:, :, :t, :hd], 1, 2)
+
+
+def _rows(x: jnp.ndarray, t_pad: int) -> jnp.ndarray:
+    """A per-row statistic (B, H, T) as the kernels' (B, H, T + t_pad, 1)
+    column."""
+    if t_pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, t_pad)))
+    return x[..., None]
 
 
 def _tile_live(q_start, k_start, *, causal: bool, window: int,
@@ -93,6 +122,21 @@ def _tile_mask(q_start, k_start, *, causal: bool, window: int,
     return mask
 
 
+def _online_softmax_step(s, mask, v, acc_ref, m_ref, l_ref):
+    """One kv tile of the online softmax: fold the masked score tile ``s``
+    (rows, kv) and its values ``v`` (kv, hd) into the running (acc, m, l)
+    scratch; m/l are (rows, 1) columns."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    # guard: rows with no live keys yet keep NEG_INF max
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())))
+    m_ref[...] = m_new
+
+
 # ------------------------------------------------------------------ forward
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
                 scale: float, causal: bool, window: int, softcap: float,
@@ -114,35 +158,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale      # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)              # (bkv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale            # (bq, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                    # (bkv, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bkv)
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           block_q=block_q, block_kv=block_kv, kv_len=kv_len)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, 0]                                   # (bq,)
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard: rows with no live keys yet keep NEG_INF max
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_ref[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[:, 0] = m_new
-        l_ref[:, 0] = l_new
+        _online_softmax_step(jnp.where(mask, s, NEG_INF), mask, v,
+                             acc_ref, m_ref, l_ref)
 
     @pl.when(kb == nkv - 1)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = m_ref[:, 0] + jnp.log(denom)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(denom)
 
 
 def flash_attention_fwd_res(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -161,42 +193,39 @@ def flash_attention_fwd_res(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     block_kv = min(block_kv, s)
     t_pad = -t % block_q
     s_pad = -s % block_kv
-    q = _pad4(q, t_pad, hd_p - hd)
-    k = _pad4(k, s_pad, hd_p - hd)
-    v = _pad4(v, s_pad, hd_p - hd)
+    qh = _head_major(q, t_pad, hd_p - hd)
+    kh = _head_major(k, s_pad, hd_p - hd)
+    vh = _head_major(v, s_pad, hd_p - hd)
     tp, sp = t + t_pad, s + s_pad
 
-    grid = (b, h, tp // block_q, sp // block_kv)
     kernel = functools.partial(
         _fwd_kernel, scale=1.0 / np.sqrt(hd), causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_kv=block_kv, kv_len=s)
-
+    q_spec = pl.BlockSpec((1, 1, block_q, hd_p),
+                          lambda b_, h_, qb, kb: (b_, h_, qb, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_kv, hd_p),
+                           lambda b_, h_, qb, kb: (b_, h_ // group, kb, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b_, h_, qb, kb: (b_, h_, qb, 0))
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd_p), lambda b_, h_, qb, kb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, qb, kb: (b_, kb, h_ // group, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, qb, kb: (b_, kb, h_ // group, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, 1, hd_p), lambda b_, h_, qb, kb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qb, kb: (b_, h_, qb)),
-        ],
+        grid=(b, h, tp // block_q, sp // block_kv),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, tp, h, hd_p), q.dtype),
-            jax.ShapeDtypeStruct((b, h, tp), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, tp, hd_p), q.dtype),
+            jax.ShapeDtypeStruct((b, h, tp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, hd_p), jnp.float32),   # acc
             pltpu.VMEM((block_q, 1), jnp.float32),      # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),      # normalizer l
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
-    return out[:, :t, :, :hd], lse[:, :, :t]
+    )(qh, kh, vh)
+    return _token_major(out, t, hd), lse[:, :, :t, 0]
 
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -250,9 +279,9 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale    # (group, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (group, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))
         if softcap > 0:
             sc = softcap * jnp.tanh(sc / softcap)
@@ -261,22 +290,14 @@ def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref,
         mask = kpos < length              # causal: everything cached is past
         if window > 0:
             mask &= (qpos - kpos) < window
-        sc = jnp.where(mask, sc, NEG_INF)
-
-        m_prev = mx_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1))
-        p = jnp.where(mask, jnp.exp(sc - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        lx_ref[:, 0] = alpha * lx_ref[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        mx_ref[:, 0] = m_new
+        _online_softmax_step(jnp.where(mask, sc, NEG_INF), mask, v,
+                             acc_ref, mx_ref, lx_ref)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        o_ref[0, 0, 0, :, :] = acc_ref[...]
-        m_ref[0, 0, 0, :] = mx_ref[:, 0]
-        l_ref[0, 0, 0, :] = lx_ref[:, 0]
+        o_ref[0, 0, 0] = acc_ref[...]
+        m_ref[0, 0, 0] = mx_ref[...]
+        l_ref[0, 0, 0] = lx_ref[...]
 
 
 def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -287,7 +308,8 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
     """Flash-decode: one query token per sequence against a paged KV cache.
 
     q: (B, H, hd) — the new token's queries.
-    k_pool/v_pool: (num_blocks, block_size, Hkv, hd) — the shared block pool.
+    k_pool/v_pool: (num_blocks, Hkv, block_size, hd) — the shared block
+        pool, head-major (`serve.kv_cache` layout).
     block_tables: (B, max_blocks) int32 — physical block of each logical
         block (rows padded with any valid block id; padded entries are
         masked out by ``lengths``).
@@ -303,7 +325,7 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
     group from one fetched block.
     """
     bsz, h, hd = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    nb, hkv, bs, _ = k_pool.shape
     group = h // hkv
     hd_p = _pad_head_dim(hd)
     if hd_p != hd:
@@ -324,26 +346,24 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / np.sqrt(hd), window=window,
         softcap=softcap, block_kv=bs, blocks_per_split=bps, group=group)
+    kv_spec = pl.BlockSpec((1, 1, bs, hd_p),
+                           lambda b, h_, s, j, tbl, lens:
+                           (tbl[b, s * bps + j], h_, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bsz, hkv, num_splits, bps),
         in_specs=[
             pl.BlockSpec((1, 1, group, hd_p),
                          lambda b, h_, s, j, tbl, lens: (b, h_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd_p),
-                         lambda b, h_, s, j, tbl, lens:
-                         (tbl[b, s * bps + j], 0, h_, 0)),
-            pl.BlockSpec((1, bs, 1, hd_p),
-                         lambda b, h_, s, j, tbl, lens:
-                         (tbl[b, s * bps + j], 0, h_, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, group, hd_p),
                          lambda b, h_, s, j, tbl, lens: (b, h_, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, group),
-                         lambda b, h_, s, j, tbl, lens: (b, h_, s, 0)),
-            pl.BlockSpec((1, 1, 1, group),
-                         lambda b, h_, s, j, tbl, lens: (b, h_, s, 0)),
+            pl.BlockSpec((1, 1, 1, group, 1),
+                         lambda b, h_, s, j, tbl, lens: (b, h_, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, group, 1),
+                         lambda b, h_, s, j, tbl, lens: (b, h_, s, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, hd_p), jnp.float32),   # unnormalized acc
@@ -357,15 +377,18 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=[
             jax.ShapeDtypeStruct((bsz, hkv, num_splits, group, hd_p),
                                  jnp.float32),
-            jax.ShapeDtypeStruct((bsz, hkv, num_splits, group), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, hkv, num_splits, group), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, hkv, num_splits, group, 1),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((bsz, hkv, num_splits, group, 1),
+                                 jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pool, v_pool)
+    m_parts, l_parts = m_parts[..., 0], l_parts[..., 0]
 
     # split combine: exact logsumexp reduction over the split axis.  Dead
     # splits carry (m=NEG_INF, l=0) and contribute exactly zero; a fully
@@ -379,16 +402,17 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 
 # ----------------------------------------------------------------- backward
-def _recompute_p_ds(q, k, v, do, lse_row, delta_row, mask, *,
+def _recompute_p_ds(q, k, v, do, lse_col, delta_col, mask, *,
                     softcap: float):
     """Shared bwd tile math: p from the lse residual, ds with the softcap
-    chain rule.  q arrives pre-scaled; all f32."""
+    chain rule.  q arrives pre-scaled; lse/delta are (bq, 1) columns; all
+    f32."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))     # (bq, bkv)
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
-    p = jnp.where(mask, jnp.exp(s - lse_row[:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_col), 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))   # (bq, bkv)
-    ds = p * (dp - delta_row[:, None])
+    ds = p * (dp - delta_col)
     if softcap > 0:
         ds = ds * (1.0 - (s / softcap) ** 2)                    # 1 - tanh^2
     return p, ds
@@ -412,20 +436,20 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        do = do_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           block_q=block_q, block_kv=block_kv, kv_len=kv_len)
-        _, ds = _recompute_p_ds(q, k, v, do, lse_ref[0, 0, :],
-                                delta_ref[0, 0, :], mask, softcap=softcap)
+        _, ds = _recompute_p_ds(q, k, v, do, lse_ref[0, 0], delta_ref[0, 0],
+                                mask, softcap=softcap)
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ()))) * scale
 
     @pl.when(kb == nkv - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -448,17 +472,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         mask = _tile_mask(q_start, k_start, causal=causal, window=window,
                           block_q=block_q, block_kv=block_kv, kv_len=kv_len)
         # dk/dv reduce over the q-head GQA group: the block carries the whole
         # group's q/do tiles, the loop is static (unrolled at trace time)
         for g in range(group):
-            q = q_ref[0, :, g, :].astype(jnp.float32) * scale
-            do = do_ref[0, :, g, :].astype(jnp.float32)
-            p, ds = _recompute_p_ds(q, k, v, do, lse_ref[0, g, :],
-                                    delta_ref[0, g, :], mask, softcap=softcap)
+            q = q_ref[0, g].astype(jnp.float32) * scale
+            do = do_ref[0, g].astype(jnp.float32)
+            p, ds = _recompute_p_ds(q, k, v, do, lse_ref[0, g],
+                                    delta_ref[0, g], mask, softcap=softcap)
             dv_acc[...] += jax.lax.dot_general(
                 p, do, (((0,), (0,)), ((), ())))                # (bkv, hd)
             dk_acc[...] += jax.lax.dot_general(
@@ -466,8 +490,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(qb == nq - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -489,67 +513,62 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     s_pad = -s_len % block_kv
     # preprocess: delta_i = sum_d do_id * o_id, in f32 (one elementwise pass)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.moveaxis(delta, 2, 1)                           # (B, H, T)
-    if t_pad:
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, t_pad)))
-        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, t_pad)))
-    qp = _pad4(q, t_pad, hd_p - hd)
-    dop = _pad4(do, t_pad, hd_p - hd)
-    kp = _pad4(k, s_pad, hd_p - hd)
-    vp = _pad4(v, s_pad, hd_p - hd)
+    delta = _rows(jnp.swapaxes(delta, 1, 2), t_pad)            # (B, H, Tp, 1)
+    lse = _rows(lse, t_pad)
+    qh = _head_major(q, t_pad, hd_p - hd)
+    doh = _head_major(do, t_pad, hd_p - hd)
+    kh = _head_major(k, s_pad, hd_p - hd)
+    vh = _head_major(v, s_pad, hd_p - hd)
     tp, sp = t + t_pad, s_len + s_pad
     scale = 1.0 / np.sqrt(hd)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_kv=block_kv, kv_len=s_len)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd_p),
+                          lambda b_, h_, qb, kb: (b_, h_, qb, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_kv, hd_p),
+                           lambda b_, h_, qb, kb: (b_, h_ // group, kb, 0))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b_, h_, qb, kb: (b_, h_, qb, 0))
     dq = pl.pallas_call(
         dq_kernel,
         grid=(b, h, tp // block_q, sp // block_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd_p), lambda b_, h_, qb, kb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, qb, kb: (b_, kb, h_ // group, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, qb, kb: (b_, kb, h_ // group, 0)),
-            pl.BlockSpec((1, block_q, 1, hd_p), lambda b_, h_, qb, kb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qb, kb: (b_, h_, qb)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h_, qb, kb: (b_, h_, qb)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd_p),
-                               lambda b_, h_, qb, kb: (b_, qb, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, tp, h, hd_p), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, tp, hd_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd_p), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
-    )(qp, kp, vp, dop, lse, delta)
+    )(qh, kh, vh, doh, lse, delta)
 
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=block_q, block_kv=block_kv, kv_len=s_len,
         group=group)
+    # grid (b, kv head, kv block, q block): the q-side blocks carry the kv
+    # head's whole group of q heads
+    gq_spec = pl.BlockSpec((1, group, block_q, hd_p),
+                           lambda b_, h_, kb, qb: (b_, h_, qb, 0))
+    grow_spec = pl.BlockSpec((1, group, block_q, 1),
+                             lambda b_, h_, kb, qb: (b_, h_, qb, 0))
+    kvo_spec = pl.BlockSpec((1, 1, block_kv, hd_p),
+                            lambda b_, h_, kb, qb: (b_, h_, kb, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(b, hkv, sp // block_kv, tp // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, group, hd_p), lambda b_, h_, kb, qb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, kb, qb: (b_, kb, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, kb, qb: (b_, kb, h_, 0)),
-            pl.BlockSpec((1, block_q, group, hd_p), lambda b_, h_, kb, qb: (b_, qb, h_, 0)),
-            pl.BlockSpec((1, group, block_q), lambda b_, h_, kb, qb: (b_, h_, qb)),
-            pl.BlockSpec((1, group, block_q), lambda b_, h_, kb, qb: (b_, h_, qb)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, kb, qb: (b_, kb, h_, 0)),
-            pl.BlockSpec((1, block_kv, 1, hd_p), lambda b_, h_, kb, qb: (b_, kb, h_, 0)),
-        ],
+        in_specs=[gq_spec, kvo_spec, kvo_spec, gq_spec, grow_spec, grow_spec],
+        out_specs=[kvo_spec, kvo_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sp, hkv, hd_p), k.dtype),
-            jax.ShapeDtypeStruct((b, sp, hkv, hd_p), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sp, hd_p), k.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sp, hd_p), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_kv, hd_p), jnp.float32),
                         pltpu.VMEM((block_kv, hd_p), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
-    )(qp, kp, vp, dop, lse, delta)
-    return (dq[:, :t, :, :hd], dk[:, :s_len, :, :hd], dv[:, :s_len, :, :hd])
+    )(qh, kh, vh, doh, lse, delta)
+    return (_token_major(dq, t, hd), _token_major(dk, s_len, hd),
+            _token_major(dv, s_len, hd))

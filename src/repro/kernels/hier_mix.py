@@ -56,8 +56,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import chunk_views, pack, pack_spec, unpack
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _kernel(x_ref, g_ref, t_ref, theta_ref, o_ref, *, eta: float):
     x = x_ref[...].astype(jnp.float32)
@@ -127,7 +125,7 @@ def hier_mix_chunks(x: jnp.ndarray, g: jnp.ndarray, t_op: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((wp, block_c), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((wp, cp), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, g, t_op, theta[:, None])
@@ -217,7 +215,7 @@ def _packed_call(x, g, op, theta, eta: float, block_c: int, interpret: bool):
     theta_spec = pl.BlockSpec((wp, 1), lambda i: (0, 0))
     out_spec = pl.BlockSpec((wp, block_c), lambda i: (0, i))
     out_shape = jax.ShapeDtypeStruct((wp, cp), jnp.float32)
-    params = _CompilerParams(dimension_semantics=("parallel",))
+    params = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
     if isinstance(op, GroupedOperator):
         d = op.scatter.shape[0]

@@ -41,17 +41,18 @@ def flash_decode_ref(q: jnp.ndarray, k_pool: jnp.ndarray,
                      softcap: float = 0.0) -> jnp.ndarray:
     """Paged single-query attention oracle (gather + dense softmax).
 
-    q: (B, H, hd); k_pool/v_pool: (num_blocks, block_size, Hkv, hd);
+    q: (B, H, hd); k_pool/v_pool: (num_blocks, Hkv, block_size, hd);
     block_tables: (B, max_blocks) int32; lengths: (B,) int32 — tokens in
     cache including the one being decoded (query position = lengths - 1).
     Rows with lengths == 0 return zeros.  -> (B, H, hd)."""
     b, h, hd = q.shape
-    nb, bs, hkv, _ = k_pool.shape
+    nb, hkv, bs, _ = k_pool.shape
     group = h // hkv
     nmax = block_tables.shape[1]
     s = nmax * bs
-    k = k_pool[block_tables].reshape(b, s, hkv, hd)   # (B, S, Hkv, hd)
-    v = v_pool[block_tables].reshape(b, s, hkv, hd)
+    # (B, nmax, Hkv, bs, hd) -> logical order (B, S, Hkv, hd)
+    k = jnp.swapaxes(k_pool[block_tables], 2, 3).reshape(b, s, hkv, hd)
+    v = jnp.swapaxes(v_pool[block_tables], 2, 3).reshape(b, s, hkv, hd)
     qg = q.reshape(b, hkv, group, hd)
     logits = jnp.einsum("bhgk,bshk->bhgs", qg, k).astype(jnp.float32)
     logits = logits / np.sqrt(hd)

@@ -51,8 +51,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 EPS = 1e-6
 
 
@@ -156,7 +154,7 @@ def _fwd_call(zx, r_gates, b_gates, *, block_b: int, chunk: int,
             pltpu.VMEM((block_b, hd), jnp.float32),   # n
             pltpu.VMEM((block_b, hd), jnp.float32),   # m
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(zx, r_gates, b_gates)
@@ -351,7 +349,7 @@ def slstm_scan_bwd(zx: jnp.ndarray, r_gates: jnp.ndarray,
             pltpu.VMEM((hd, hd4), jnp.float32),              # dR accumulator
             pltpu.VMEM((1, hd4), jnp.float32),               # db accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(zx, r_gates, b_gates, hb, cb, nb_state, mb, dh)

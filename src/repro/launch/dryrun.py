@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede every other import (jax locks device count on first init);
+# the 512 placeholder devices are host devices, so an attached chip is
+# never claimed.
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) combination with ShapeDtypeStruct stand-ins (no device allocation).
 

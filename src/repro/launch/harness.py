@@ -48,7 +48,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -151,6 +150,12 @@ class TrainHarness:
     active mask into the counter-based gate draw (deadline = the legacy
     lock-step trainer bit for bit), ``"forced"`` uses the mask as the gate.
 
+    Every entry point DONATES its incoming state: the new state is written
+    over the old one's buffers, so a fleet of W full-width replicas needs
+    one copy of its state on the device, not two.  Callers rebind
+    (``state, m = h.local_scan(state, ...)``) and never reuse a state they
+    passed in.
+
     With ``mesh=`` (a mesh carrying a `workers` axis, e.g.
     ``make_mesh((4, 2), ("workers", "data"))``) every entry point compiles
     to `shard_map` over that mesh instead of single-device vmap: each
@@ -170,6 +175,10 @@ class TrainHarness:
     W than at shard width W/num_shards, so it can wobble in the final
     ulp (gradients of a mean are order-independent, which is why the
     state itself never drifts).  Tests pin it with allclose(rtol=1e-5).
+    The contract is tested on the CPU at per-worker batch 2.  At batch 1
+    XLA:CPU rounds the shard-width and vmap-width gradient programs
+    differently, so single bf16 roundings differ and trajectories drift
+    apart over slots (`chip_smoke.py --chips 4` measures both).
     """
 
     def __init__(self, cfg: ArchConfig, mll: MLLConfig, st: MLLState, *,
@@ -274,7 +283,7 @@ class TrainHarness:
         (the spmd-free twin — `fn` itself calls collectives that can't
         trace outside shard_map) with the lead-axis rule — both cached
         per arg structure/shapes, so each pow2 scan chunk compiles once,
-        exactly like the plain jit path.  ``check_rep`` is off: the
+        exactly like the plain jit path.  ``check_vma`` is off: the
         lowerings index full-width tables with `axis_index`, which the
         replication checker can't see through.
 
@@ -282,7 +291,7 @@ class TrainHarness:
         underlying jitted function for those shapes — tests lower it to
         compiled HLO to assert mixing became psum/ppermute collectives."""
         if self.mesh is None:
-            jitted = jax.jit(fn)
+            jitted = jax.jit(fn, donate_argnums=0)
             jitted.build = lambda *args: jitted
             return jitted
         mesh, w = self.mesh, self.num_workers
@@ -300,9 +309,9 @@ class TrainHarness:
                 out_specs = jax.tree.map(
                     partial(_worker_spec, w=w, axis=0),
                     jax.eval_shape(shape_fn or fn, *args))
-                cache[key] = jax.jit(shard_map(
+                cache[key] = jax.jit(jax.shard_map(
                     fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False))
+                    check_vma=False), donate_argnums=0)
             return cache[key]
 
         def call(*args):
@@ -401,6 +410,7 @@ class HarnessRun:
     network: Any
     calibration: timeline.RateCalibration | None = None
     trace_path: str | None = None
+    harness: TrainHarness | None = None   # the compiled steps that ran
 
 
 def _boundaries(plan: timeline.TimelinePlan, start: int, stop: int,
@@ -428,6 +438,9 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
              mesh=None, overlap: str = "none", overlap_chunks: int = 4,
              log: Callable = print) -> HarnessRun:
     """Drive a compiled `TrainHarness` over the whole plan.
+
+    ``train_state`` is consumed: the harness donates it to its first
+    step (see `TrainHarness`).
 
     ``mesh`` switches the harness to shard_map execution (see
     `TrainHarness`): the incoming state is laid out on the mesh up front,
@@ -529,4 +542,4 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
         out_trace = timeline.export_trace(trace_path, plan, **meta)
     return HarnessRun(history=history, avg_params=u, train_state=train_state,
                       plan=plan, network=network, calibration=calibration,
-                      trace_path=out_trace)
+                      trace_path=out_trace, harness=harness)

@@ -491,15 +491,22 @@ def analyze_hlo(hlo_text: str, *, pod_stride: int = 0) -> HloCosts:
                 if shapes:
                     costs.flops += m * float(
                         np.prod(shapes[0][1]) if shapes[0][1] else 1)
-            # ---- collectives
-            if op.opcode in COLLECTIVES:
-                cb = m * rbytes
-                costs.collective_bytes += cb
-                costs.collective_counts[op.opcode] += m
-                costs.collective_bytes_by_op[op.opcode] += cb
-                if _crosses_pods(op.line, pod_stride):
-                    costs.dcn_bytes += cb
-                coll_details.append((cb, op.opcode, op.result_type, cname))
+            # ---- collectives.  The TPU compiler splits them into async
+            # X-start / X-done pairs: the start counts the call, the done's
+            # result is the bytes moved (the start's is an operand tuple)
+            coll, part = op.opcode, ""
+            if coll.endswith(("-start", "-done")):
+                coll, part = coll.rsplit("-", 1)
+            if coll in COLLECTIVES:
+                if part != "done":
+                    costs.collective_counts[coll] += m
+                if part != "start":
+                    cb = m * rbytes
+                    costs.collective_bytes += cb
+                    costs.collective_bytes_by_op[coll] += cb
+                    if _crosses_pods(op.line, pod_stride):
+                        costs.dcn_bytes += cb
+                    coll_details.append((cb, coll, op.result_type, cname))
             # ---- HBM bytes (fusion boundaries only; in-place DUS)
             if not count_bytes or op.opcode in _CONTROL_OPS:
                 continue

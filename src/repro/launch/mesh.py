@@ -18,12 +18,7 @@ from typing import Sequence
 
 import jax
 from jax.experimental import mesh_utils
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 requires explicit axis types; 0.4.x has implicit Auto only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
@@ -50,8 +45,6 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
             f"device_count={n} before importing jax (CPU), or run on a "
             "large enough slice")
     dev_mesh = mesh_utils.create_device_mesh(shape, devices[:n])
-    if AxisType is None:
-        return Mesh(dev_mesh, axes)
     return Mesh(dev_mesh, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -42,6 +42,7 @@ from repro.core.protocol import (available_mixing, describe_mixing,
 from repro.core.timeline import (RATE_MODELS, RateCalibration,
                                  available_policies, get_policy)
 from repro.data.pipeline import LMBatcher, make_token_stream, rng_from_state
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.harness import (CALIBRATION_FILE, measure_worker_rates,
                                   plan_config, resolve_measured_network,
                                   run_plan)
@@ -124,7 +125,8 @@ def run_training(cfg: ArchConfig, mll: MLLConfig, loop: TrainLoopConfig,
     readiness policy's `TimelinePlan` for ``loop.steps`` slots, and executes
     it.  With ``policy="deadline"`` + the Bernoulli rate model this
     reproduces the legacy per-tick loop bit for bit (regression-tested).
-    Returns loss history + final averaged params (+ plan/trace/state).
+    Returns loss history + final averaged params (+ plan/trace/state, and
+    the `TrainHarness` whose compiled steps ran the plan).
     """
     if loop.impl not in ("xla", "flash", "pallas"):
         raise ValueError(f"unknown impl {loop.impl!r} (xla | flash | pallas)")
@@ -234,7 +236,7 @@ def run_training(cfg: ArchConfig, mll: MLLConfig, loop: TrainLoopConfig,
     return {"history": run.history, "avg_params": run.avg_params,
             "network": run.network, "plan": run.plan,
             "train_state": run.train_state, "calibration": run.calibration,
-            "trace_path": run.trace_path}
+            "trace_path": run.trace_path, "harness": run.harness}
 
 
 def main(argv=None):
@@ -295,6 +297,7 @@ def main(argv=None):
     ap.add_argument("--trace", default=None,
                     help="export the event trace (simulator schema) here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.mixing == "list":
         print(describe_mixing())

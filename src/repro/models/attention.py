@@ -234,7 +234,7 @@ def attention_paged_decode(params: PyTree, x: jnp.ndarray, cfg: ArchConfig,
                            ) -> tuple[jnp.ndarray, PyTree]:
     """One-token decode against the paged block pool.
 
-    x: (B, 1, d); pools: {"k_pool", "v_pool"} (num_blocks, bs, Hkv, hd);
+    x: (B, 1, d); pools: {"k_pool", "v_pool"} (num_blocks, Hkv, bs, hd);
     block_tables: (B, max_blocks) int32; lengths: (B,) int32 — context
     length INCLUDING the token being decoded (it sits at position
     ``lengths - 1``; 0 marks an inactive lane, whose write is dropped and

@@ -166,7 +166,7 @@ def init_stacked_paged_state(cfg: ArchConfig, num_blocks: int,
                              block_size: int) -> PyTree:
     """Per-layer paged block pools, stacked on the super-block axis:
     {"pos{i}": {"k_pool", "v_pool"}} with leaves
-    (n_sb, num_blocks, block_size, Hkv, hd)."""
+    (n_sb, num_blocks, Hkv, block_size, hd)."""
     from repro.serve import kv_cache as kvc
 
     _require_attn_only(cfg, "paged decode")

@@ -21,9 +21,9 @@ active lanes by one token.  Admission is FIFO and all-or-nothing on cache
 blocks; finished requests free their blocks immediately for reuse.
 
 **Cache layout** (`kv_cache`): per attention layer, one shared pool of
-``num_blocks`` fixed-size blocks, shape (num_blocks, block_size, Hkv, hd).
+``num_blocks`` fixed-size blocks, shape (num_blocks, Hkv, block_size, hd).
 A request's context is a row of the (max_batch, max_blocks) block table;
-logical position p lives at ``pool[table[lane, p // bs], p % bs]``.
+logical position p lives at ``pool[table[lane, p // bs], :, p % bs]``.
 Decode reads the table either through an XLA gather (`gather_kv` + masked
 SDPA, the oracle) or the Pallas flash-decode kernel
 (`kernels.ops.flash_decode`: split-KV grid, in-kernel block-table

@@ -1,18 +1,20 @@
 """Paged/block KV cache for the batched serving engine.
 
 Layout: one shared pool of ``num_blocks`` fixed-size blocks per attention
-layer, shape (num_blocks, block_size, Hkv, head_dim).  A request's cache is
-a row of the BLOCK TABLE — (max_batch, max_blocks_per_seq) int32 physical
-block ids — so requests of different lengths batch together and a finished
-request's blocks return to the free list for immediate reuse.  Logical
-token position p of lane b lives at
-``pool[table[b, p // block_size], p % block_size]``.
+layer, shape (num_blocks, Hkv, block_size, head_dim) — head-major, so one
+kv head of one block is a contiguous (block_size, head_dim) tile, the unit
+the flash-decode kernel fetches.  A request's cache is a row of the BLOCK
+TABLE — (max_batch, max_blocks_per_seq) int32 physical block ids — so
+requests of different lengths batch together and a finished request's
+blocks return to the free list for immediate reuse.  Logical token
+position p of lane b lives at
+``pool[table[b, p // block_size], :, p % block_size]``.
 
 Everything device-side here is functional (pure jnp in, new arrays out) so
 the write helpers compose inside jitted/scanned model code; the
 `BlockAllocator` is the host-side free list the engine drives admission
 with.  Writes for inactive lanes / padded positions are routed to a
-one-past-the-end flat index and dropped (``.at[].set(mode="drop")``) —
+one-past-the-end block id and dropped (``.at[].set(mode="drop")``) —
 no masking data dependencies inside the kernel path.
 """
 from __future__ import annotations
@@ -49,18 +51,15 @@ class PagedCacheConfig:
 def init_layer_pools(pc: PagedCacheConfig, n_kv_heads: int, head_dim: int,
                      dtype) -> dict[str, jnp.ndarray]:
     """One attention layer's {k_pool, v_pool}."""
-    shape = (pc.num_blocks, pc.block_size, n_kv_heads, head_dim)
+    shape = (pc.num_blocks, n_kv_heads, pc.block_size, head_dim)
     return {"k_pool": jnp.zeros(shape, dtype), "v_pool": jnp.zeros(shape, dtype)}
 
 
-def _flat_write(pool: jnp.ndarray, flat_idx: jnp.ndarray,
-                values: jnp.ndarray) -> jnp.ndarray:
-    """Scatter ``values`` (N, Hkv, hd) at flat token slots (N,) of the pool;
-    out-of-range indices (the drop sentinel) are discarded."""
-    nb, bs = pool.shape[:2]
-    flat = pool.reshape(nb * bs, *pool.shape[2:])
-    flat = flat.at[flat_idx].set(values.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+def _token_write(pool: jnp.ndarray, blk: jnp.ndarray, off: jnp.ndarray,
+                 values: jnp.ndarray) -> jnp.ndarray:
+    """Scatter ``values`` (N, Hkv, hd) at token slots (blk[n], off[n]) of the
+    pool; out-of-range block ids (the drop sentinel) are discarded."""
+    return pool.at[blk, :, off].set(values.astype(pool.dtype), mode="drop")
 
 
 def write_token_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
@@ -71,13 +70,14 @@ def write_token_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
 
     k/v: (B, Hkv, hd); positions: (B,) absolute position of the new token,
     negative = inactive lane (write dropped)."""
-    nb, bs = k_pool.shape[:2]
-    b = positions.shape[0]
+    nb, bs = k_pool.shape[0], k_pool.shape[2]
     safe = jnp.maximum(positions, 0)
     blk = jnp.take_along_axis(block_tables, (safe // bs)[:, None],
                               axis=1)[:, 0]
-    flat = jnp.where(positions >= 0, blk * bs + safe % bs, nb * bs)
-    return (_flat_write(k_pool, flat, k), _flat_write(v_pool, flat, v))
+    blk = jnp.where(positions >= 0, blk, nb)
+    off = safe % bs
+    return (_token_write(k_pool, blk, off, k),
+            _token_write(v_pool, blk, off, v))
 
 
 def write_prefill_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
@@ -88,21 +88,23 @@ def write_prefill_kv(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
 
     k/v: (B, S, Hkv, hd) from the batched forward pass; plens: (B,) — only
     positions < plens[b] are written (pad tail dropped)."""
-    nb, bs = k_pool.shape[:2]
+    nb, bs = k_pool.shape[0], k_pool.shape[2]
     b, s = k.shape[:2]
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     blk = jnp.take_along_axis(block_tables, pos // bs, axis=1)     # (B, S)
-    flat = jnp.where(pos < plens[:, None], blk * bs + pos % bs, nb * bs)
-    return (_flat_write(k_pool, flat.reshape(-1), k.reshape(b * s, *k.shape[2:])),
-            _flat_write(v_pool, flat.reshape(-1), v.reshape(b * s, *v.shape[2:])))
+    blk = jnp.where(pos < plens[:, None], blk, nb).reshape(-1)
+    off = (pos % bs).reshape(-1)
+    return (_token_write(k_pool, blk, off, k.reshape(b * s, *k.shape[2:])),
+            _token_write(v_pool, blk, off, v.reshape(b * s, *v.shape[2:])))
 
 
 def gather_kv(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
     """Dense view of a paged pool: (B, max_blocks * block_size, Hkv, hd)
     in logical position order (the XLA decode path's input)."""
     b, nmax = block_tables.shape
-    nb, bs = pool.shape[:2]
-    return pool[block_tables].reshape(b, nmax * bs, *pool.shape[2:])
+    _, hkv, bs, hd = pool.shape
+    return jnp.swapaxes(pool[block_tables], 2, 3).reshape(
+        b, nmax * bs, hkv, hd)
 
 
 class BlockAllocator:

@@ -81,6 +81,25 @@ def test_collective_bytes_and_counts():
     assert costs.dcn_bytes == 0
 
 
+ASYNC = textwrap.dedent("""\
+    HloModule async
+
+    ENTRY %main.1 (x: bf16[24,14,64]) -> bf16[24,14,64] {
+      %x = bf16[24,14,64]{2,1,0} parameter(0)
+      %cps = (bf16[24,14,64]{2,1,0}, bf16[24,14,64]{2,1,0}, u32[], u32[]) collective-permute-start(%x), channel_id=1, source_target_pairs={{0,1},{1,0}}
+      ROOT %cpd = bf16[24,14,64]{2,1,0} collective-permute-done(%cps)
+    }
+    """)
+
+
+def test_async_collective_pairs_count_once():
+    """TPU HLO splits a collective into X-start / X-done: one call, and
+    the bytes of the done's result."""
+    costs = analyze_hlo(ASYNC)
+    assert costs.collective_counts == {"collective-permute": 1}
+    assert costs.collective_bytes == 24 * 14 * 64 * 2
+
+
 def test_dus_fusion_in_place_bytes():
     """The DUS-rooted fusion must charge ~2 update slices, not the full
     12x buffer."""

@@ -415,8 +415,8 @@ def _paged_case(key, b, hkv, group, hd, bs, nb, nmax, lengths):
     """Random pools + a permuted block table + query for a decode case."""
     kq, kk, kv, kt = jax.random.split(key, 4)
     q = jax.random.normal(kq, (b, hkv * group, hd), jnp.float32)
-    k_pool = jax.random.normal(kk, (nb, bs, hkv, hd), jnp.float32)
-    v_pool = jax.random.normal(kv, (nb, bs, hkv, hd), jnp.float32)
+    k_pool = jax.random.normal(kk, (nb, hkv, bs, hd), jnp.float32)
+    v_pool = jax.random.normal(kv, (nb, hkv, bs, hd), jnp.float32)
     # each lane gets a distinct random set of physical blocks — the kernel
     # must follow the indirection, not read the pool in order
     perm = jax.random.permutation(kt, nb)[:b * nmax].reshape(b, nmax)

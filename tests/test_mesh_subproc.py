@@ -79,18 +79,14 @@ def test_mesh_collective_equivalence():
     einsum lowering (psum/all-gather collectives) implements T_k exactly."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
         from jax.experimental import mesh_utils
         from repro.core.mllsgd import (MLLConfig, apply_schedule, build_network,
                                        build_state)
         from repro.core.simulator import apply_operator
 
         devs = mesh_utils.create_device_mesh((2, 4), jax.devices()[:8])
-        try:
-            from jax.sharding import AxisType
-            mesh = Mesh(devs, ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
-        except ImportError:
-            mesh = Mesh(devs, ("pod", "data"))
+        mesh = Mesh(devs, ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
         cfg = MLLConfig(tau=2, q=2, eta=0.1, hub_topology="ring",
                         granularity="worker_per_data")
         net = build_network(cfg, 2, 4)

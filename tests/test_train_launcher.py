@@ -1,8 +1,11 @@
 """End-to-end launcher test: the production code path trains a tiny LM on
 CPU and the averaged model's loss goes down."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.registry import get_smoke_config
 from repro.core.mllsgd import MLLConfig
@@ -37,3 +40,25 @@ def test_run_training_checkpoint(tmp_path):
     assert step == 4
     for a, b in zip(jax.tree.leaves(out["avg_params"]), jax.tree.leaves(u)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("placed", [None, "elsewhere"])
+def test_enable_compile_cache(monkeypatch, tmp_path, placed):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the fixed, gitignored <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if placed is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        got = compile_cache.enable_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if placed is None:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == after == os.path.join(repo, ".jax_cache")
+    else:
+        assert got == str(tmp_path) and after == before
