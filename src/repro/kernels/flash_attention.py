@@ -223,6 +223,7 @@ def flash_attention_fwd_res(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
         interpret=interpret,
     )(qh, kh, vh)
     return _token_major(out, t, hd), lse[:, :, :t, 0]
@@ -385,6 +386,7 @@ def flash_decode_paged(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_decode",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, k_pool, v_pool)
@@ -541,6 +543,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((b, h, tp, hd_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd_p), jnp.float32)],
         compiler_params=params,
+        name="flash_dq",
         interpret=interpret,
     )(qh, kh, vh, doh, lse, delta)
 
@@ -568,6 +571,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((block_kv, hd_p), jnp.float32),
                         pltpu.VMEM((block_kv, hd_p), jnp.float32)],
         compiler_params=params,
+        name="flash_dkv",
         interpret=interpret,
     )(qh, kh, vh, doh, lse, delta)
     return (_token_major(dq, t, hd), _token_major(dk, s_len, hd),

@@ -127,6 +127,7 @@ def hier_mix_chunks(x: jnp.ndarray, g: jnp.ndarray, t_op: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((wp, cp), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="hier_mix",
         interpret=interpret,
     )(x, g, t_op, theta[:, None])
     return out[:w, :c]
@@ -239,6 +240,7 @@ def _packed_call(x, g, op, theta, eta: float, block_c: int, interpret: bool):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=out_shape, compiler_params=params,
+            name="hier_mix",
             interpret=interpret)(*operands)
 
     t_op = op
@@ -250,6 +252,7 @@ def _packed_call(x, g, op, theta, eta: float, block_c: int, interpret: bool):
         in_specs=xgt_specs + [pl.BlockSpec((wp, wp), lambda i: (0, 0)),
                               theta_spec],
         out_specs=out_spec, out_shape=out_shape, compiler_params=params,
+        name="hier_mix",
         interpret=interpret)(x, g, t_op, theta[:, None])
 
 

@@ -156,6 +156,7 @@ def _fwd_call(zx, r_gates, b_gates, *, block_b: int, chunk: int,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="slstm_fwd",
         interpret=interpret,
     )(zx, r_gates, b_gates)
     if not save_bounds:
@@ -351,6 +352,7 @@ def slstm_scan_bwd(zx: jnp.ndarray, r_gates: jnp.ndarray,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="slstm_bwd",
         interpret=interpret,
     )(zx, r_gates, b_gates, hb, cb, nb_state, mb, dh)
     dr = jnp.sum(drp, axis=0).astype(r_gates.dtype)

@@ -40,6 +40,7 @@ Beyond the executor, the harness owns the production run lifecycle:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from functools import partial
@@ -55,6 +56,7 @@ from repro.core import protocol, timeline
 from repro.core.mllsgd import MLLConfig, MLLState
 from repro.core.simulator import weighted_average
 from repro.data.pipeline import LMBatcher, rng_state
+from repro.launch import spans
 from repro.train import checkpoint
 from repro.train.train_step import loss_fn, mll_harness_step
 
@@ -109,6 +111,11 @@ def resolve_measured_network(network, calibration: timeline.RateCalibration):
 # ----------------------------------------------------------------- harness
 def _stack_batches(batches: list[dict]) -> dict:
     return {k: jnp.stack([b[k] for b in batches]) for k in batches[0]}
+
+
+def _draw(batcher: LMBatcher, rng: np.random.Generator) -> dict:
+    with spans.span(spans.DRAW_BATCH):
+        return batcher.sample(rng)
 
 
 def _worker_spec(x, w: int, axis: int = 0) -> P:
@@ -235,44 +242,68 @@ class TrainHarness:
         # shard_map — the global output shapes are identical either way
         ref = partial(mll_harness_step, cfg=cfg, mll=mll, st=st,
                       gate_mode=gate_mode, impl=impl)
+        # (entry point, phase, chunk length) -> times traced.  Counted in
+        # the Python body of each entry point, which runs only while JAX
+        # traces it, so a call that hits the compiled program counts
+        # nothing.  The dense entries have no phase (None).
+        self.retraces: collections.Counter = collections.Counter()
+        traced = self._traced
 
         def last_metrics(state_metrics):
             state, ms = state_metrics
             return state, jax.tree.map(lambda m: m[-1], ms)
 
-        def make_local_scan(stepfn):
+        def make_local_scan(stepfn, count: bool):
             def impl(state, batches, active):
+                if count:
+                    self.retraces["local_scan", protocol.PHASE_LOCAL,
+                                  int(active.shape[0])] += 1
+
                 def body(s, xs):
                     b, act = xs
                     return stepfn(s, b, act)
                 return jax.lax.scan(body, state, (batches, active))
             return lambda s, b, a: last_metrics(impl(s, b, a))
 
-        # second argument per entry: worker-axis position inside each
+        # The wrappers keep the XLA module names a profiler trace shows
+        # (a lambda: jit__lambda, a partial: jit__unknown).
+        # Second argument per entry: worker-axis position inside each
         # positional arg for the shard_map specs (None = replicate the
         # whole arg — the composed (W, W) event operator is contracted in
         # full by every shard).  Stacked scan batches carry workers at 1.
         self.local_scan = self._wrap(
-            make_local_scan(step), (0, 1, 1), make_local_scan(ref))
+            make_local_scan(step, True), (0, 1, 1),
+            make_local_scan(ref, False))
         self.event_step = {
-            ph: self._wrap(partial(step, phase=ph), (0, 0, 0),
+            ph: self._wrap(partial(traced, ("event_step", ph, 1), step,
+                                   phase=ph), (0, 0, 0),
                            partial(ref, phase=ph))
             for ph in (protocol.PHASE_SUBNET, protocol.PHASE_HUB)}
         self.dense_step = self._wrap(
-            lambda s, b, a, op: step(s, b, a, op=op), (0, 0, 0, None),
+            lambda s, b, a, op: traced(("dense_step", None, 1), step,
+                                       s, b, a, op=op),
+            (0, 0, 0, None),
             lambda s, b, a, op: ref(s, b, a, op=op))
         # all-idle event slots (forced plans: a barrier round whose cost
         # exceeds tau ends in mixing with every gate at zero) skip the
         # backward pass and the θ=0 no-op update — loss metrics + mix only
         self.event_step_idle = {
-            ph: self._wrap(partial(step, phase=ph, compute_grads=False),
+            ph: self._wrap(partial(traced, ("event_step_idle", ph, 1), step,
+                                   phase=ph, compute_grads=False),
                            (0, 0, 0),
                            partial(ref, phase=ph, compute_grads=False))
             for ph in (protocol.PHASE_SUBNET, protocol.PHASE_HUB)}
         self.dense_step_idle = self._wrap(
-            lambda s, b, a, op: step(s, b, a, op=op, compute_grads=False),
+            lambda s, b, a, op: traced(("dense_step_idle", None, 1), step,
+                                       s, b, a, op=op, compute_grads=False),
             (0, 0, 0, None),
             lambda s, b, a, op: ref(s, b, a, op=op, compute_grads=False))
+
+    def _traced(self, key: tuple, fn, train_state, batch, active, **kwargs):
+        """``fn(train_state, batch, active, **kwargs)``, counting a trace
+        of entry ``key`` (the argument names are the program's)."""
+        self.retraces[key] += 1
+        return fn(train_state, batch, active, **kwargs)
 
     def _wrap(self, fn, rules, shape_fn=None):
         """jit one entry point; under a mesh, `shard_map` it first.
@@ -330,7 +361,19 @@ class TrainHarness:
 
         One batch is drawn per slot (the data-cursor contract resumable
         checkpoints rely on); all-idle runs of forced plans advance the
-        cursor and the slot counter without computing gradients."""
+        cursor and the slot counter without computing gradients.
+
+        The host's work is marked with `spans` (names in `launch.spans`):
+        the call itself (``run_span``), each batch drawn, each stack and
+        copy of a launch's inputs, each launch of a compiled step (a
+        dispatch span covers the call alone, so its length is the host's
+        time to launch) and each fast-forward.  The dispatch and
+        fast-forward spans carry the slots they cover (``slots``)."""
+        with spans.span(spans.RUN_SPAN, lo=lo, hi=hi):
+            return self._run_span(state, plan, batcher, rng, lo, hi,
+                                  last_metrics)
+
+    def _run_span(self, state, plan, batcher, rng, lo, hi, last_metrics):
         op_mats = plan.op_mats or {}
         forced = plan.gate_mode == "forced"
         s = lo
@@ -344,8 +387,9 @@ class TrainHarness:
                     j = off                      # all-idle run: fast-forward
                     while j < e and not plan.active[j].any():
                         j += 1
-                    batcher.skip(rng, j - off)
-                    state = state._replace(step=state.step + (j - off))
+                    with spans.span(spans.SKIP_IDLE, slots=j - off):
+                        batcher.skip(rng, j - off)
+                        state = state._replace(step=state.step + (j - off))
                     off = j
                     continue
                 j = off
@@ -357,25 +401,33 @@ class TrainHarness:
                 run = j - off
                 while run:
                     k = 1 << (run.bit_length() - 1)   # pow2: O(log) compiles
-                    batches = _stack_batches(
-                        [batcher.sample(rng) for _ in range(k)])
-                    state, last_metrics = self.local_scan(
-                        state, batches, jnp.asarray(plan.active[off:off + k]))
+                    drawn = [_draw(batcher, rng) for _ in range(k)]
+                    with spans.span(spans.STACK_BATCHES, slots=k):
+                        batches = _stack_batches(drawn)
+                        act = jnp.asarray(plan.active[off:off + k])
+                    with spans.span(spans.LOCAL_SCAN, slots=k):
+                        state, last_metrics = self.local_scan(
+                            state, batches, act)
                     off += k
                     run -= k
             if e < hi:                          # the event slot itself
-                batch = batcher.sample(rng)
-                act = jnp.asarray(plan.active[e])
+                batch = _draw(batcher, rng)
                 idle = forced and not plan.active[e].any()
-                if e in op_mats:
+                with spans.span(spans.STACK_BATCHES, slots=1):
+                    act = jnp.asarray(plan.active[e])
+                    op = jnp.asarray(op_mats[e]) if e in op_mats else None
+                if op is not None:
                     fn = self.dense_step_idle if idle else self.dense_step
-                    state, last_metrics = fn(state, batch, act,
-                                             jnp.asarray(op_mats[e]))
+                    with spans.span(spans.DENSE_STEP, slots=1,
+                                    idle=int(idle)):
+                        state, last_metrics = fn(state, batch, act, op)
                 else:
+                    ph = int(plan.op_ids[e])
                     table = (self.event_step_idle if idle
                              else self.event_step)
-                    state, last_metrics = table[int(plan.op_ids[e])](
-                        state, batch, act)
+                    with spans.span(spans.event_step(ph), slots=1,
+                                    idle=int(idle)):
+                        state, last_metrics = table[ph](state, batch, act)
             s = e + 1
         return state, last_metrics
 
@@ -478,11 +530,14 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
     done = start_slot
     final_u = None
     stop = plan.slots if stop_slot is None else min(stop_slot, plan.slots)
+    traced = None       # the harness's traces after the first boundary
     for b in _boundaries(plan, start_slot, stop, eval_every,
                          checkpoint_every):
         train_state, last_metrics = harness.run_span(
             train_state, plan, batcher, rng, done, b, last_metrics)
         done = b
+        if traced is None:
+            traced = dict(harness.retraces)
         u = None
         if (eval_every and done % eval_every == 0) or done == plan.slots:
             u = weighted_average(gather(train_state.params), a)
@@ -500,8 +555,10 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
             history["step"].append(done)
             history["loss"].append(wl)
             history["avg_loss"].append(float(avg_loss))
+            retraced = (f"  retraces {dict(harness.retraces)}"
+                        if harness.retraces != traced else "")
             log(f"slot {done:5d}  worker-loss {wl:.4f}  u_k-loss "
-                f"{float(avg_loss):.4f}  ({time.time()-t0:.1f}s)")
+                f"{float(avg_loss):.4f}  ({time.time()-t0:.1f}s){retraced}")
         want_ckpt = (checkpoint_dir and checkpoint_every
                      and done % checkpoint_every == 0) or \
                     (checkpoint_dir and done == stop)

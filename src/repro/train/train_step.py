@@ -36,6 +36,7 @@ from repro.core import protocol
 from repro.core.mllsgd import MLLConfig, MLLState, apply_schedule, gate_sample, gated_sgd_update
 from repro.core.protocol import MLLTrainState, protocol_step
 from repro.core.timeline import apply_event_operator, chunked_apply_operator
+from repro.launch import spans
 from repro.models import model as model_mod
 from repro.models.pjit_utils import constraint
 
@@ -201,6 +202,12 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
     width then sliced — the counter-based draw is shape-dependent, so this
     keeps gates bit-identical to the vmap path on every shard layout.
 
+    The step's parts run under named scopes (`launch.spans`): ``mll.grads``
+    (forward, loss, backward), ``mll.update`` (gate draw and inner update)
+    and ``mll.mix.subnet`` / ``mll.mix.hub`` / ``mll.mix.dense`` (the
+    mixing event), so the ops of the compiled program carry them in their
+    ``op_name`` metadata.  Scopes change metadata alone, not the program.
+
     ``compute_grads=False`` is the ALL-IDLE event slot (forced plans: the
     straggler tail of a barrier round ends in mixing with every worker's
     gate at zero): the backward pass and the θ=0 inner update — a state
@@ -224,56 +231,64 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
                          "expected none|chunked")
     step = train_state.step.astype(jnp.int32) + 1
     if compute_grads:
-        grads, metrics = per_worker_grads(train_state.params, batch, cfg,
-                                          spmd_axis_name=spmd_axis_name,
-                                          impl=impl, remat=remat,
-                                          microbatch=microbatch,
-                                          accum_dtype=mll.accum_dtype)
-        active = active.astype(st.rates.dtype)
-        if gate_mode == "bernoulli":
-            theta = gate_sample(mll.seed, step, st.rates)
-            if spmd is not None and spmd.size > 1:
-                theta = jax.lax.dynamic_slice_in_dim(
-                    theta, spmd.offset(), spmd.per_shard, 0)
-            theta = theta * active
-        else:
-            theta = active
-        optimizer = protocol.resolve_inner_optimizer(mll)
-        params, opt_state = protocol.gated_inner_update(
-            optimizer, train_state.params, train_state.opt_state, grads,
-            theta)
+        with jax.named_scope(spans.GRADS):
+            grads, metrics = per_worker_grads(train_state.params, batch, cfg,
+                                              spmd_axis_name=spmd_axis_name,
+                                              impl=impl, remat=remat,
+                                              microbatch=microbatch,
+                                              accum_dtype=mll.accum_dtype)
+        with jax.named_scope(spans.UPDATE):
+            active = active.astype(st.rates.dtype)
+            if gate_mode == "bernoulli":
+                theta = gate_sample(mll.seed, step, st.rates)
+                if spmd is not None and spmd.size > 1:
+                    theta = jax.lax.dynamic_slice_in_dim(
+                        theta, spmd.offset(), spmd.per_shard, 0)
+                theta = theta * active
+            else:
+                theta = active
+            optimizer = protocol.resolve_inner_optimizer(mll)
+            params, opt_state = protocol.gated_inner_update(
+                optimizer, train_state.params, train_state.opt_state, grads,
+                theta)
     else:
-        loss, m = jax.vmap(partial(loss_fn, cfg=cfg, impl=impl,
-                                   remat=remat))(train_state.params, batch)
+        with jax.named_scope(spans.GRADS):      # the forward alone
+            loss, m = jax.vmap(partial(loss_fn, cfg=cfg, impl=impl,
+                                       remat=remat))(train_state.params,
+                                                     batch)
         metrics = {"loss": loss, **m}
         params, opt_state = train_state.params, train_state.opt_state
     mix_state = train_state.mix_state
     sharded = spmd is not None and spmd.size > 1
     chunked = overlap == "chunked"
     if op is not None:
-        if chunked:
-            params = chunked_apply_operator(params, op, overlap_chunks)
-        else:
-            params = apply_event_operator(params, op, spmd=spmd)
-    elif chunked and phase != protocol.PHASE_LOCAL:
-        op_mat = st.v_op if phase == protocol.PHASE_SUBNET else st.z_op
-        params = chunked_apply_operator(params, op_mat, overlap_chunks)
+        with jax.named_scope(spans.MIX_DENSE):
+            if chunked:
+                params = chunked_apply_operator(params, op, overlap_chunks)
+            else:
+                params = apply_event_operator(params, op, spmd=spmd)
     elif phase != protocol.PHASE_LOCAL:
-        # mix_state is always populated up front (init_train_state) — a
-        # structure change mid-run would retrace every compiled segment
-        strategy = protocol.resolve_mixing(mll)
-        if phase == protocol.PHASE_SUBNET:
-            if sharded:
-                params, mix_state = strategy.subnet_spmd_with_state(
-                    params, st, mix_state, spmd)
+        subnet = phase == protocol.PHASE_SUBNET
+        with jax.named_scope(spans.MIX_SUBNET if subnet else spans.MIX_HUB):
+            if chunked:
+                op_mat = st.v_op if subnet else st.z_op
+                params = chunked_apply_operator(params, op_mat,
+                                                overlap_chunks)
             else:
-                params, mix_state = strategy.subnet_with_state(
-                    params, st, mix_state)
-        else:
-            if sharded:
-                params, mix_state = strategy.hub_spmd_with_state(
-                    params, st, mix_state, spmd)
-            else:
-                params, mix_state = strategy.hub_with_state(params, st,
-                                                            mix_state)
+                # mix_state is always populated up front
+                # (init_train_state) — a structure change mid-run would
+                # retrace every compiled segment
+                strategy = protocol.resolve_mixing(mll)
+                if subnet and sharded:
+                    params, mix_state = strategy.subnet_spmd_with_state(
+                        params, st, mix_state, spmd)
+                elif subnet:
+                    params, mix_state = strategy.subnet_with_state(
+                        params, st, mix_state)
+                elif sharded:
+                    params, mix_state = strategy.hub_spmd_with_state(
+                        params, st, mix_state, spmd)
+                else:
+                    params, mix_state = strategy.hub_with_state(
+                        params, st, mix_state)
     return MLLTrainState(params, opt_state, mix_state, step), metrics

@@ -95,3 +95,65 @@ def test_hier_mix_packed_compiles(one_chip):
         tree, tree, _sds(one_chip, (w, w), jnp.float32),
         _sds(one_chip, (w,), jnp.float32))
     assert n == 1
+
+
+@pytest.mark.parametrize("entry", ["local_scan", "event_step"])
+def test_step_programs_match_the_benchmark_names(one_chip, monkeypatch,
+                                                 entry):
+    """The trainer's step programs, compiled with the Pallas kernels at the
+    smoke config, still carry what `chipbench/names.json` matches in a
+    chip trace: the XLA module name of each entry point and the output
+    signature of each flash kernel.  The kernels carry their own names
+    and sit in the gradient scope."""
+    import dataclasses
+    import json
+    import re
+
+    from repro.configs.registry import get_smoke_config
+    from repro.core import protocol
+    from repro.core.mllsgd import MLLConfig, build_network, build_state
+    from repro.core.protocol import init_train_state
+    from repro.kernels import ops
+    from repro.launch import spans
+    from repro.launch.harness import TrainHarness
+    from repro.launch.train import replicate_params
+    from repro.models import model as model_mod
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                           "chipbench", "names.json")) as f:
+        names = json.load(f)
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = get_smoke_config("qwen2-0.5b")
+    w, seq = 4, 128
+    mll = MLLConfig(tau=2, q=2, eta=0.005, hub_topology="ring")
+    network = build_network(
+        dataclasses.replace(mll, granularity="worker_per_data"), 2, 2)
+    st = build_state(mll, network)
+    h = TrainHarness(cfg, mll, st, gate_mode="bernoulli", impl="flash")
+    state = jax.eval_shape(lambda: init_train_state(replicate_params(
+        model_mod.init_model(jax.random.PRNGKey(0), cfg), w), cfg=mll))
+    state = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), state)
+    tokens = _sds(one_chip, (w, 1, seq), jnp.int32)
+    if entry == "local_scan":
+        fn, lead = h.local_scan, (1,)
+    else:
+        fn, lead = h.event_step[protocol.PHASE_HUB], ()
+    batch = {"tokens": _sds(one_chip, lead + tokens.shape, jnp.int32),
+             "labels": _sds(one_chip, lead + tokens.shape, jnp.int32)}
+    active = _sds(one_chip, lead + (w,), jnp.bool_)
+    text = fn.lower(state, batch, active).compile().as_text()
+    module = re.search(r"^HloModule (\S+?),", text, re.M).group(1)
+    assert re.search(names[entry], f"{module}(1234)")
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if CUSTOM_CALL in ln]
+    kinds = [[bool(re.search(names[k], c)) for k in ("flash_fwd",
+                                                      "flash_bwd")]
+             for c in calls]
+    assert sorted(map(tuple, kinds)) == [(False, True)] * 2 + [(True, False)]
+    kernels = sorted(re.match(r"%(\w+)\.\d+ = ", c).group(1) for c in calls)
+    assert kernels == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert all(re.search(r'op_name="[^"]*' + re.escape(spans.GRADS), c)
+               for c in calls)
+    scopes = set(re.findall(r'op_name="[^"]*?(mll\.[a-z.]+)', text))
+    mix = {spans.MIX_HUB} if entry == "event_step" else set()
+    assert scopes == {spans.GRADS, spans.UPDATE} | mix
