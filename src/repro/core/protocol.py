@@ -301,6 +301,66 @@ def hub_average_two_stage(stacked: PyTree, st: MLLState,
     return jax.tree.map(mix, stacked)
 
 
+def _chain_sum(terms: list, dtype) -> jnp.ndarray:
+    """terms[0] + terms[1] + ... added in ascending order in ``dtype``.
+
+    The first add is written ``terms[1] + terms[0]``.  IEEE addition
+    commutes, so without contraction the bits are the same; XLA:CPU
+    contracts an f32 product into the add that consumes it, the left
+    operand's first, and so contracts each LATER product into the running
+    sum, as it does in `_product_mean`'s reduce and `_roll_mix`'s adds."""
+    ts = [t.astype(dtype) for t in terms]
+    acc = ts[0] if len(ts) == 1 else ts[1] + ts[0]
+    for t in ts[2:]:
+        acc = acc + t
+    return acc
+
+
+def _round_to(x: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``x.astype(dtype)`` with the rounding made an op of its own.
+
+    XLA:TPU computes a fusion's 16-bit float ops in f32 and drops the casts
+    between them, so a rounding that the composed form makes by writing a
+    bf16 array (the updated params, the subnet mean) would vanish inside
+    the one fused loop of the row form.  An explicit ``reduce_precision``
+    keeps it; on values already rounded it changes nothing."""
+    dtype = jnp.dtype(dtype)
+    fi = jnp.finfo(dtype)
+    if fi.bits < 32:
+        x = jax.lax.reduce_precision(x.astype(jnp.float32),
+                                     exponent_bits=fi.nexp,
+                                     mantissa_bits=fi.nmant)
+    return x.astype(dtype)
+
+
+def two_stage_rows(rows: list, st: MLLState, *, hub: bool) -> jnp.ndarray:
+    """`subnet_average_two_stage` (``hub=False``) or `hub_average_two_stage`
+    of ONE leaf given as its W worker rows, returned stacked.
+
+    The same products and adds, in the same order, as the composed form:
+    the subnet mean sums in the accumulator `jnp.sum` uses (float32 for
+    16-bit floats) and rounds once, the hub mix adds in ascending roll
+    order; the input rows and the hub models are rounded where the
+    composed form writes them (`_round_to`).  But every output row is an
+    elementwise expression of static worker rows: no reduce over the
+    worker axis, no roll, no broadcast back.  XLA can then fuse whatever
+    produced the rows (the gated update) into the one loop that writes
+    the mixed leaf."""
+    d, nd = _grouped_dims(st)
+    dt = rows[0].dtype
+    acc = jnp.float32 if dt in (jnp.bfloat16, jnp.float16) else dt
+    v = st.v_weights.astype(dt)
+    x = [_round_to(r, dt) for r in rows]
+    z = [_round_to(_chain_sum([v[i] * x[i]
+                               for i in range(k * nd, (k + 1) * nd)], acc),
+                   dt) for k in range(d)]                    # hub models
+    if hub:                     # `_roll_mix` with H indexed statically
+        h = st.h.astype(dt)
+        z = [_chain_sum([h[(e + o) % d, e] * z[(e + o) % d]
+                         for o in range(d)], dt) for e in range(d)]
+    return jnp.stack([z[i // nd] for i in range(d * nd)])
+
+
 def _grouped_spmd_z(x, st: MLLState, spmd: SpmdAxis, sps: int,
                     mix_dtype: str | None):
     """This shard's sub-network mean (no worker axis): local weighted
@@ -812,6 +872,17 @@ class MixingStrategy:
                        state: PyTree) -> tuple[PyTree, PyTree]:
         return self.hub(stacked, st), state
 
+    # ---- row form (single-device event slots, `mll_harness_step`)
+    def has_rows(self) -> bool:
+        """Whether `mix_rows` computes this strategy's events bit for bit."""
+        return False
+
+    def mix_rows(self, rows: list[PyTree], st: MLLState, *,
+                 hub: bool) -> PyTree:
+        """The stateless subnet (``hub=False``) or hub event over the W
+        per-worker trees ``rows``, returned as one stacked tree."""
+        raise NotImplementedError
+
     # ---- SPMD (shard_map) lowering: inputs/outputs are this shard's
     # (W/size, ...) worker rows; collectives run over ``spmd.name``
     def validate_spmd(self, st: MLLState, spmd: SpmdAxis) -> None:
@@ -920,6 +991,20 @@ class TwoStageMixing(MixingStrategy):
 
     def hub(self, stacked, st):
         return hub_average_two_stage(stacked, st, self.mix_dtype)
+
+    def has_rows(self):
+        # a subclass that replaces either event or adds state (ppermute and
+        # the whole compression ladder do) keeps the composed form; so does
+        # a mix_dtype, whose casts XLA may elide differently in each form
+        cls = type(self)
+        return self.mix_dtype is None and all(
+            getattr(cls, n) is getattr(TwoStageMixing, n)
+            for n in ("subnet", "hub", "init_state", "subnet_with_state",
+                      "hub_with_state"))
+
+    def mix_rows(self, rows, st, *, hub):
+        return jax.tree.map(
+            lambda *r: two_stage_rows(list(r), st, hub=hub), *rows)
 
     def validate_spmd(self, st, spmd):
         super().validate_spmd(st, spmd)
@@ -1160,6 +1245,32 @@ def gated_inner_update(optimizer: optim_mod.Optimizer, stacked: PyTree,
     params = jax.tree.map(sel, new_p, stacked)
     inner = jax.tree.map(sel, new_inner, opt_state["inner"])
     return params, {"inner": inner, "counts": counts}
+
+
+def gated_update_rows(optimizer: optim_mod.Optimizer, stacked: PyTree,
+                      opt_state: PyTree, grads: PyTree, theta: jnp.ndarray,
+                      ) -> tuple[list[PyTree], PyTree]:
+    """`gated_inner_update` worker by worker: returns the W updated
+    per-worker trees (static slices of the worker axis) instead of one
+    stacked tree, so a `MixingStrategy.mix_rows` event can consume each
+    row where it is computed.  Same bits as `gated_inner_update`.
+
+    Only for optimizers whose state holds no arrays (``sgd``): a stateful
+    optimizer (momentum, adamw) would have to slice and restack its state
+    as well, and keeps the composed form."""
+    if jax.tree.leaves(opt_state["inner"]):
+        raise ValueError("gated_update_rows needs an optimizer whose state "
+                         "holds no arrays; use gated_inner_update")
+    gate = theta != 0
+    counts = opt_state["counts"] + gate.astype(jnp.int32)
+    rows = []
+    for i in range(theta.shape[0]):
+        old = jax.tree.map(lambda x: x[i], stacked)
+        new, _ = optimizer.update(jax.tree.map(lambda g: g[i], grads),
+                                  opt_state["inner"], old, counts[i])
+        rows.append(jax.tree.map(
+            lambda n, o: jnp.where(gate[i], n, o.astype(n.dtype)), new, old))
+    return rows, {"inner": opt_state["inner"], "counts": counts}
 
 
 def resolve_inner_optimizer(cfg) -> optim_mod.Optimizer:

@@ -58,7 +58,7 @@ from repro.core.simulator import weighted_average
 from repro.data.pipeline import LMBatcher, rng_state
 from repro.launch import spans
 from repro.train import checkpoint
-from repro.train.train_step import loss_fn, mll_harness_step
+from repro.train.train_step import event_form, loss_fn, mll_harness_step
 
 PyTree = Any
 
@@ -247,6 +247,10 @@ class TrainHarness:
         # traces it, so a call that hits the compiled program counts
         # nothing.  The dense entries have no phase (None).
         self.retraces: collections.Counter = collections.Counter()
+        # (phase, form) -> times an event entry was traced in that form
+        # (`train_step.event_form`: "rows" or "composed"; dense entries
+        # have no phase)
+        self.event_forms: collections.Counter = collections.Counter()
         traced = self._traced
 
         def last_metrics(state_metrics):
@@ -301,8 +305,14 @@ class TrainHarness:
 
     def _traced(self, key: tuple, fn, train_state, batch, active, **kwargs):
         """``fn(train_state, batch, active, **kwargs)``, counting a trace
-        of entry ``key`` (the argument names are the program's)."""
+        of entry ``key`` and the form of its event (the argument names are
+        the program's)."""
         self.retraces[key] += 1
+        form = event_form(self.mll, train_state.opt_state,
+                          phase=kwargs.get("phase", protocol.PHASE_LOCAL),
+                          op=kwargs.get("op"), spmd=self.spmd,
+                          overlap=self.overlap)
+        self.event_forms[key[1], form] += 1
         return fn(train_state, batch, active, **kwargs)
 
     def _wrap(self, fn, rules, shape_fn=None):
@@ -531,6 +541,7 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
     final_u = None
     stop = plan.slots if stop_slot is None else min(stop_slot, plan.slots)
     traced = None       # the harness's traces after the first boundary
+    forms = {}          # the event forms last logged
     for b in _boundaries(plan, start_slot, stop, eval_every,
                          checkpoint_every):
         train_state, last_metrics = harness.run_span(
@@ -557,6 +568,9 @@ def run_plan(cfg: ArchConfig, mll: MLLConfig, network, st: MLLState,
             history["avg_loss"].append(float(avg_loss))
             retraced = (f"  retraces {dict(harness.retraces)}"
                         if harness.retraces != traced else "")
+            if harness.event_forms != forms:
+                forms = dict(harness.event_forms)
+                retraced += f"  event forms {forms}"
             log(f"slot {done:5d}  worker-loss {wl:.4f}  u_k-loss "
                 f"{float(avg_loss):.4f}  ({time.time()-t0:.1f}s){retraced}")
         want_ckpt = (checkpoint_dir and checkpoint_every

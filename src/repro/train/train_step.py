@@ -165,6 +165,32 @@ def mll_transformer_state_step(train_state: MLLTrainState, batch: dict,
     return new_state, metrics
 
 
+def event_form(mll: MLLConfig, opt_state: PyTree, *, phase: int,
+               op: jnp.ndarray | None = None,
+               spmd: protocol.SpmdAxis | None = None,
+               overlap: str = "none") -> str:
+    """How `mll_harness_step` writes a slot's gated update and mixing event.
+
+    ``"rows"``: worker by worker (`protocol.gated_update_rows` into the
+    strategy's `mix_rows`), so XLA fuses the update and the mixing into
+    one pass over each leaf that reads the params and gradients once and
+    writes the mixed params once.  Taken for a subnet or hub event on one
+    device (no ``spmd`` axis) with no composed ``op`` and no ``chunked``
+    overlap, when the strategy has a row form (`two_stage`) and the inner
+    optimizer's state holds no arrays (``sgd``).
+
+    ``"composed"``: the inner update over the stacked tree, then the
+    strategy's event: every other slot (shard_map, dense operators,
+    ``chunked``, the other strategies, stateful optimizers).  Both forms
+    give the same bits."""
+    if (phase != protocol.PHASE_LOCAL and op is None and spmd is None
+            and overlap == "none"
+            and not jax.tree.leaves(opt_state["inner"])
+            and protocol.resolve_mixing(mll).has_rows()):
+        return "rows"
+    return "composed"
+
+
 def mll_harness_step(train_state: MLLTrainState, batch: dict,
                      active: jnp.ndarray, cfg: ArchConfig, mll: MLLConfig,
                      st: MLLState, *, gate_mode: str = "bernoulli",
@@ -208,6 +234,13 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
     mixing event), so the ops of the compiled program carry them in their
     ``op_name`` metadata.  Scopes change metadata alone, not the program.
 
+    An event slot on one device runs in the form `event_form` picks: the
+    ``"rows"`` form updates and mixes each leaf worker row by worker row,
+    so the update (under ``mll.update``) and the mixing (under
+    ``mll.mix.*``) compile to one pass over the leaf; the ``"composed"``
+    form updates the stacked tree, then mixes it.  The state is the same
+    bit for bit either way.
+
     ``compute_grads=False`` is the ALL-IDLE event slot (forced plans: the
     straggler tail of a barrier round ends in mixing with every worker's
     gate at zero): the backward pass and the θ=0 inner update — a state
@@ -230,6 +263,8 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
         raise ValueError(f"unknown overlap {overlap!r}; "
                          "expected none|chunked")
     step = train_state.step.astype(jnp.int32) + 1
+    rows = event_form(mll, train_state.opt_state, phase=phase, op=op,
+                      spmd=spmd, overlap=overlap) == "rows"
     if compute_grads:
         with jax.named_scope(spans.GRADS):
             grads, metrics = per_worker_grads(train_state.params, batch, cfg,
@@ -248,9 +283,10 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
             else:
                 theta = active
             optimizer = protocol.resolve_inner_optimizer(mll)
-            params, opt_state = protocol.gated_inner_update(
-                optimizer, train_state.params, train_state.opt_state, grads,
-                theta)
+            update = (protocol.gated_update_rows if rows
+                      else protocol.gated_inner_update)
+            params, opt_state = update(optimizer, train_state.params,
+                                       train_state.opt_state, grads, theta)
     else:
         with jax.named_scope(spans.GRADS):      # the forward alone
             loss, m = jax.vmap(partial(loss_fn, cfg=cfg, impl=impl,
@@ -258,6 +294,9 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
                                                      batch)
         metrics = {"loss": loss, **m}
         params, opt_state = train_state.params, train_state.opt_state
+        if rows:
+            params = [jax.tree.map(lambda x: x[i], params)
+                      for i in range(st.rates.shape[0])]
     mix_state = train_state.mix_state
     sharded = spmd is not None and spmd.size > 1
     chunked = overlap == "chunked"
@@ -274,6 +313,9 @@ def mll_harness_step(train_state: MLLTrainState, batch: dict,
                 op_mat = st.v_op if subnet else st.z_op
                 params = chunked_apply_operator(params, op_mat,
                                                 overlap_chunks)
+            elif rows:
+                params = protocol.resolve_mixing(mll).mix_rows(
+                    params, st, hub=not subnet)
             else:
                 # mix_state is always populated up front
                 # (init_train_state) — a structure change mid-run would

@@ -97,6 +97,33 @@ def test_hier_mix_packed_compiles(one_chip):
     assert n == 1
 
 
+def _smoke_harness(one_chip, monkeypatch, mixing="dense"):
+    """A `TrainHarness` at the smoke config with the Pallas kernels
+    compiled for the chip, W = 4 (two sub-networks of two, ring hubs), and
+    its train state as shapes on one chip."""
+    import dataclasses
+
+    from repro.configs.registry import get_smoke_config
+    from repro.core.mllsgd import MLLConfig, build_network, build_state
+    from repro.core.protocol import init_train_state
+    from repro.kernels import ops
+    from repro.launch.harness import TrainHarness
+    from repro.launch.train import replicate_params
+    from repro.models import model as model_mod
+
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    cfg = get_smoke_config("qwen2-0.5b")
+    mll = MLLConfig(tau=2, q=2, eta=0.005, hub_topology="ring",
+                    mixing=mixing)
+    network = build_network(
+        dataclasses.replace(mll, granularity="worker_per_data"), 2, 2)
+    st = build_state(mll, network)
+    h = TrainHarness(cfg, mll, st, gate_mode="bernoulli", impl="flash")
+    state = jax.eval_shape(lambda: init_train_state(replicate_params(
+        model_mod.init_model(jax.random.PRNGKey(0), cfg), 4), cfg=mll))
+    return h, jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), state)
+
+
 @pytest.mark.parametrize("entry", ["local_scan", "event_step"])
 def test_step_programs_match_the_benchmark_names(one_chip, monkeypatch,
                                                  entry):
@@ -105,34 +132,17 @@ def test_step_programs_match_the_benchmark_names(one_chip, monkeypatch,
     chip trace: the XLA module name of each entry point and the output
     signature of each flash kernel.  The kernels carry their own names
     and sit in the gradient scope."""
-    import dataclasses
     import json
     import re
 
-    from repro.configs.registry import get_smoke_config
     from repro.core import protocol
-    from repro.core.mllsgd import MLLConfig, build_network, build_state
-    from repro.core.protocol import init_train_state
-    from repro.kernels import ops
     from repro.launch import spans
-    from repro.launch.harness import TrainHarness
-    from repro.launch.train import replicate_params
-    from repro.models import model as model_mod
 
     with open(os.path.join(os.path.dirname(__file__), os.pardir,
                            "chipbench", "names.json")) as f:
         names = json.load(f)
-    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
-    cfg = get_smoke_config("qwen2-0.5b")
+    h, state = _smoke_harness(one_chip, monkeypatch)
     w, seq = 4, 128
-    mll = MLLConfig(tau=2, q=2, eta=0.005, hub_topology="ring")
-    network = build_network(
-        dataclasses.replace(mll, granularity="worker_per_data"), 2, 2)
-    st = build_state(mll, network)
-    h = TrainHarness(cfg, mll, st, gate_mode="bernoulli", impl="flash")
-    state = jax.eval_shape(lambda: init_train_state(replicate_params(
-        model_mod.init_model(jax.random.PRNGKey(0), cfg), w), cfg=mll))
-    state = jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), state)
     tokens = _sds(one_chip, (w, 1, seq), jnp.int32)
     if entry == "local_scan":
         fn, lead = h.local_scan, (1,)
@@ -157,3 +167,83 @@ def test_step_programs_match_the_benchmark_names(one_chip, monkeypatch,
     scopes = set(re.findall(r'op_name="[^"]*?(mll\.[a-z.]+)', text))
     mix = {spans.MIX_HUB} if entry == "event_step" else set()
     assert scopes == {spans.GRADS, spans.UPDATE} | mix
+
+
+@pytest.mark.parametrize("phase", ["subnet", "hub"])
+def test_event_programs_update_and_mix_in_one_pass(one_chip, monkeypatch,
+                                                   phase):
+    """A two_stage event slot at the smoke config, compiled for the chip:
+    the update and the mixing run in the row form, so no top-level
+    `reduce` is left in the mixing scope, and a top-level `broadcast`
+    there (if any) copies one worker row to every worker: under the
+    ring's H at D = 2 both hub rows are the same bits, XLA computes that
+    hub model once and broadcasts it.  The fused loops that update keep
+    the rounding of the updated params."""
+    import re
+
+    from repro.core import protocol
+    from repro.launch import spans
+
+    h, state = _smoke_harness(one_chip, monkeypatch, "two_stage")
+    ph = protocol.PHASE_SUBNET if phase == "subnet" else protocol.PHASE_HUB
+    w, seq = 4, 128
+    batch = {k: _sds(one_chip, (w, 1, seq), jnp.int32)
+             for k in ("tokens", "labels")}
+    text = h.event_step[ph].lower(
+        state, batch, _sds(one_chip, (w,), jnp.bool_)).compile().as_text()
+    assert dict(h.event_forms) == {(ph, "rows"): 1}
+    scope = spans.MIX_SUBNET if phase == "subnet" else spans.MIX_HUB
+    entry = text[text.index("\nENTRY"):].splitlines()[1:]
+    top = [re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\((.*)", ln) for ln in entry]
+    mixing = [m for m, ln in zip(top, entry)
+              if m and 'op_name="' in ln and scope in ln]
+    assert mixing, "no top-level instruction in the mixing scope"
+    assert not [m for m in mixing if m.group(2) == "reduce"]
+    for m in mixing:
+        if m.group(2) == "broadcast":
+            rank = len(m.group(1).split(","))
+            dims = re.search(r"dimensions=\{([\d,]*)\}", m.group(3)).group(1)
+            assert dims == ",".join(map(str, range(1, rank)))
+    # inside each fused loop that updates, the bf16 roundings of the
+    # updated params are ops of their own (`protocol._round_to`)
+    fused = re.findall(r"\n(%fused_computation[^\n]*\{\n(?:.*\n)*?\})", text)
+    updating = [c for c in fused if spans.UPDATE in c and " subtract(" in c]
+    assert updating
+    assert all("reduce-precision(" in c for c in updating)
+
+
+def test_update_and_hub_mix_read_and_write_each_weight_once(one_chip):
+    """The gated update and a ring hub event (D = 2 sub-networks of 2) in
+    the row form, on a stand-in of four qwen2-0.5b-shaped bf16 leaves at
+    W = 4 (1.04e9 elements): the compiler's `bytes accessed` is at most
+    8 B per element, against 6 B for reading params and gradients and
+    writing the mix once (the composed form reads 17 B)."""
+    import dataclasses
+    import math
+
+    from repro.core import protocol
+    from repro.core.mllsgd import MLLConfig, build_network, build_state
+    from repro.optim import optimizers
+
+    shapes = [(4, 24, 896, 4864), (4, 24, 896, 896), (4, 151936, 896),
+              (4, 24, 896)]
+    tree = [_sds(one_chip, s) for s in shapes]
+    mll = MLLConfig(hub_topology="ring", mixing="two_stage")
+    st = build_state(mll, build_network(
+        dataclasses.replace(mll, granularity="worker_per_data"), 2, 2))
+    sgd = optimizers.sgd(0.005)
+    strategy = protocol.get_mixing("two_stage")
+
+    def event(params, opt_state, grads, theta):
+        rows, opt_state = protocol.gated_update_rows(sgd, params, opt_state,
+                                                     grads, theta)
+        return strategy.mix_rows(rows, st, hub=True), opt_state
+
+    opt_state = {"inner": (), "counts": _sds(one_chip, (4,), jnp.int32)}
+    compiled = jax.jit(event, donate_argnums=0).lower(
+        tree, opt_state, tree, _sds(one_chip, (4,), jnp.float32)).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    elements = sum(math.prod(s) for s in shapes)
+    assert cost["bytes accessed"] / elements <= 8.0
