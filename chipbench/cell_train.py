@@ -9,6 +9,12 @@ follows; together they run every program the window runs), then on to a
 hub-round boundary a whole round later.  The window then runs whole hub
 rounds through `TrainHarness.run_span` until ``seconds`` have passed, and
 ends at `block_until_ready` on the state.
+
+Without a ``mesh`` in the traffic every worker is vmapped on the first
+chip.  With ``"mesh": [workers, data]`` the harness runs its `shard_map`
+path over a mesh of exactly the cell's chips, axes ("workers", "data"),
+and the state is made already laid out on it: each worker's model on its
+shard of the ``workers`` axis, never all of them on one chip.
 """
 from __future__ import annotations
 
@@ -20,6 +26,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 import tokens as tokens_mod
 from weights import LAYER_KEYS, Spec, from_program, make_weights, to_program
@@ -42,26 +49,11 @@ def arch_config(name: str, spec: Spec):
         compute_dtype=spec.dtype)
 
 
-class Annotated:
-    """Calls ``fn`` inside a profiler span named ``name``: host labels for
-    the trace's idle gaps."""
-
-    def __init__(self, fn, name: str):
-        self.fn, self.name = fn, name
-
-    def __call__(self, *a, **k):
-        with jax.profiler.TraceAnnotation(self.name):
-            return self.fn(*a, **k)
-
-    def __getattr__(self, attr):
-        return getattr(self.fn, attr)
-
-
 class TrainCell:
     """One training cell: harness, state, data feed, and plan."""
 
-    def __init__(self, name: str, cfg: dict, traffic: dict, seed: int, *,
-                 annotate: bool = False):
+    def __init__(self, name: str, cfg: dict, traffic: dict, seed: int,
+                 devices: list):
         from repro.core.mllsgd import MLLConfig, build_network, build_state
         from repro.core.protocol import init_train_state
         from repro.core.timeline import get_policy
@@ -88,9 +80,10 @@ class TrainCell:
             network, mll.schedule, PLAN_SLOTS, np.random.default_rng(seed),
             rate_model="bernoulli")
         self.round = t["tau"] * t["q"]
+        self.mesh = cell_mesh(t, devices)
         self.harness = TrainHarness(self.arch, mll, st,
                                     gate_mode=self.plan.gate_mode,
-                                    impl=t["impl"])
+                                    impl=t["impl"], mesh=self.mesh)
         w = self.workers
 
         def init(w0):
@@ -102,7 +95,13 @@ class TrainCell:
         w0 = make_weights(self.spec, seed)
         shapes = jax.eval_shape(init, w0)
         self._check_layout(shapes.params)
-        self.state = jax.jit(init)(w0)
+        if self.mesh is None:
+            self.state = jax.jit(init)(w0)
+        else:
+            from repro.launch.harness import _worker_spec
+            self.state = jax.jit(init, out_shardings=jax.tree.map(
+                lambda x: NamedSharding(self.mesh, _worker_spec(x, w)),
+                shapes))(w0)
         del w0
         stream = tokens_mod.bigram_stream(
             w, t["tokens_per_worker"], self.spec.vocab, seed, **t["bigram"])
@@ -111,15 +110,6 @@ class TrainCell:
         self.metrics = None
         self.slot = 0
         self.round_s = None
-        if annotate:
-            self.batcher.sample = Annotated(self.batcher.sample, "draw_batch")
-            self.harness.local_scan = Annotated(self.harness.local_scan,
-                                                "local_scan")
-            self.harness.event_step = {
-                k: Annotated(v, f"event_step.{k}")
-                for k, v in self.harness.event_step.items()}
-        self._span = (Annotated(self.harness.run_span, "run_span")
-                      if annotate else self.harness.run_span)
 
     def _check_layout(self, params) -> None:
         """Fail loudly if the trainer's parameter tree no longer matches
@@ -162,7 +152,7 @@ class TrainCell:
 
     # ------------------------------------------------------------ driving
     def advance(self, hi: int) -> None:
-        self.state, self.metrics = self._span(
+        self.state, self.metrics = self.harness.run_span(
             self.state, self.plan, self.batcher, self.rng, self.slot, hi,
             self.metrics)
         self.slot = hi
@@ -235,8 +225,19 @@ class TrainCell:
 
     def free(self) -> None:
         """Drop the trainer's state before the reference runs."""
-        self.state = self.metrics = None
-        self.harness = self._span = None
+        self.state = self.metrics = self.harness = None
+
+
+def cell_mesh(traffic: dict, devices: list):
+    """The ("workers", "data") mesh over exactly ``devices`` that the
+    traffic's ``mesh`` asks for, or None for vmap on the first chip."""
+    if "mesh" not in traffic:
+        return None
+    from jax.experimental import mesh_utils
+    from jax.sharding import AxisType, Mesh
+    shape = tuple(traffic["mesh"])
+    return Mesh(mesh_utils.create_device_mesh(shape, devices),
+                ("workers", "data"), axis_types=(AxisType.Auto,) * 2)
 
 
 def first_batches(spec: Spec, traffic: dict, seed: int, n: int) -> list:

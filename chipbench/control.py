@@ -58,12 +58,12 @@ def main(argv=None) -> int:
     for seed in [int(s) for s in args.seeds.split(",") if s]:
         t0 = time.time()
         tc = cell_train.TrainCell(cell["config_name"], cell["config"], train,
-                                  seed)
+                                  seed, devices)
         prog = tc.follow(slots)
         tc.free()
         ref = reference.run(spec, train, seed, train["gate_seed"],
                             cell_train.first_batches(spec, train, seed,
-                                                     slots[-1]), devices[0])
+                                                     slots[-1]), devices)
         reading("program", seed, prog, ref, t0)
     mixes = any(reference.phase(k, train["tau"], train["q"])
                 for k in range(1, slots[-1] + 1))
@@ -74,11 +74,11 @@ def main(argv=None) -> int:
     for seed in [int(s) for s in args.control_seeds.split(",") if s]:
         batches = cell_train.first_batches(spec, train, seed, slots[-1])
         ref = reference.run(spec, train, seed, train["gate_seed"], batches,
-                            devices[0])
+                            devices)
         for kind, kw in faults.items():
             t0 = time.time()
             got = reference.run(spec, train, seed, train["gate_seed"], batches,
-                                devices[0], **kw)
+                                devices, **kw)
             reading(kind, seed, got, ref, t0)
     return 0
 

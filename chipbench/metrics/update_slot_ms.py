@@ -1,6 +1,6 @@
 """Device time of the ops charged to the trainer's ``mll.update`` scope
-(the gate draw and the gated inner-optimizer update) in the programs that
-mix nothing, the local scans, per local slot, averaged over the cell's
+(the gate draw and the gated inner-optimizer update) in the local scans,
+the programs that mix nothing, per local slot, averaged over the cell's
 chips.  In an event program XLA fuses most of the update into the
 mixing, which `mix_event_ms` reads."""
 import spans
@@ -10,6 +10,6 @@ def read(ctx):
     m = spans.of(ctx)
     if m is None:
         return None
-    return spans.per_slot_ms(
-        spans.part_ns(m, spans.UPDATE, spans.unmixed(m)),
-        spans.local_slots(m))
+    local = spans.programs(m, spans.LOCAL_SCAN)
+    return spans.per_slot_ms(spans.part_ns(m, spans.UPDATE, local),
+                             spans.local_slots(m))

@@ -21,10 +21,12 @@ backward, are rounded after a per-tensor scale (the tensor's largest
 magnitude maps to the dtype's largest value): the control that `correct`
 must reject.
 
-Memory: one worker is run layer by layer.  The forward keeps each layer's
-input (T x d floats); the backward walks the layers in reverse, updating
-each layer's weights as soon as its gradient exists, so no full gradient
-and no float32 copy of the model is ever held.
+Memory: worker i lives on chip i mod chips of the cell, so no chip holds
+more models than the trainer puts there, and each worker is run layer by
+layer.  The forward keeps each layer's input (T x d floats); the backward
+walks the layers in reverse, updating each layer's weights as soon as its
+gradient exists, so no full gradient and no float32 copy of the model is
+ever held.
 """
 from __future__ import annotations
 
@@ -262,7 +264,10 @@ def _mix_terms(xs, coef, dtype):
     return acc.astype(dtype)
 
 
-def _mix(workers: list, t: np.ndarray) -> list:
+def _mix(workers: list, t: np.ndarray, home: list) -> list:
+    """Column j of ``t`` mixes the workers' models into worker j's, each
+    leaf formed on worker j's device (``home[j]``) from copies of its
+    sources."""
     n = len(workers)
     leaves = [jax.tree.leaves(w) for w in workers]
     treedef = jax.tree.structure(workers[0])
@@ -271,16 +276,18 @@ def _mix(workers: list, t: np.ndarray) -> list:
         for j in range(n):
             src = [i for i in range(n) if t[i, j] != 0]
             coef = jnp.asarray([t[i, j] for i in src], F32)
-            out[j].append(_mix_terms(tuple(leaves[i][li] for i in src), coef,
-                                     leaves[j][li].dtype))
+            out[j].append(_mix_terms(
+                tuple(jax.device_put(leaves[i][li], home[j]) for i in src),
+                coef, leaves[j][li].dtype))
     return [jax.tree.unflatten(treedef, o) for o in out]
 
 
 def run(spec: Spec, train: dict, seed: int, gate_seed: int,
-        batches: list[dict], device, *, lowp: str | None = None,
+        batches: list[dict], devices: list, *, lowp: str | None = None,
         keep: float = 1.0, exchange: bool = True) -> dict:
-    """Follow the first ``len(batches)`` MLL-SGD steps from the seed on
-    ``device``.
+    """Follow the first ``len(batches)`` MLL-SGD steps from the seed, worker
+    i living and stepping on ``devices[i % len(devices)]`` (the cell's
+    chips: W models need not fit on one).
 
     ``train`` holds the schedule (tau, q, eta, subnets, workers_per_subnet,
     topology, rates) and ``check_slots``, the steps whose losses are
@@ -298,15 +305,19 @@ def run(spec: Spec, train: dict, seed: int, gate_seed: int,
                                  train["workers_per_subnet"],
                                  train["topology"])
     wk = Worker(spec, lowp, keep)
-    w0 = jax.device_put(_split_layers(make_weights(spec, seed), spec), device)
-    workers = [w0] * n
+    home = [devices[i % len(devices)] for i in range(n)]
+    split = _split_layers(make_weights(spec, seed), spec)
+    start = {d: jax.device_put(split, d) for d in home}
+    del split
+    workers = [start[d] for d in home]
     losses, deltas, grad1 = [], [], {}
     with jax.default_matmul_precision("highest"), \
-            jax.default_device(device):
+            jax.default_device(devices[0]):
         for k, batch in enumerate(batches, start=1):
             theta = gates(gate_seed, k, train["rates"])
-            step = [wk.step(workers[i], jnp.asarray(batch["tokens"][i]),
-                            jnp.asarray(batch["labels"][i]),
+            step = [wk.step(workers[i],
+                            jax.device_put(batch["tokens"][i], home[i]),
+                            jax.device_put(batch["labels"][i], home[i]),
                             jnp.float32(train["eta"] * theta[i]))
                     for i in range(n)]
             workers = [s[1] for s in step]
@@ -320,13 +331,14 @@ def run(spec: Spec, train: dict, seed: int, gate_seed: int,
                 # every hub first averages its sub-network (a model, stored
                 # in the configured dtype like every model); at a hub event
                 # the hubs then mix those averages (Algorithm 1)
-                workers = _mix(workers, v_op)
+                workers = _mix(workers, v_op, home)
                 if ph == 2:
-                    workers = _mix(workers, z_op)
+                    workers = _mix(workers, z_op, home)
             if k in report:
                 deltas.append({
                     f"w{i}/{key}": float(val) for i in range(n)
-                    for key, val in _flat_norms(w0, workers[i]).items()})
+                    for key, val in _flat_norms(start[home[i]],
+                                                workers[i]).items()})
     return {"slots": sorted(report),
             "loss": np.asarray([[float(x) for x in row] for row in losses]),
             "grad1": grad1,

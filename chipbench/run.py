@@ -11,7 +11,8 @@ in ``chipbench/limits/<cell>.json``, and each per-layer metric's reader in
 adds files and entries; no file here changes.
 
 The run exits non-zero, printing no result, where JAX finds no TPU or fewer
-chips than the cell asks for, or the program is missing.  With
+chips than the cell asks for, the program is missing, or the traffic's
+``mesh`` does not fit the cell.  With
 ``--trace 0`` it reports the cell's end-to-end metrics; with ``--trace 1``
 its per-layer metrics, read from a profiler trace of the window.  Either
 way it then checks what the timed path produced against the plain
@@ -37,8 +38,9 @@ import types  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 CACHE = os.path.join(ROOT, ".chipbench_cache")
-LABELS = ("run_span", "local_scan", "event_step.1", "event_step.2",
-          "draw_batch")
+# the trainer's host spans (`repro.launch.spans`): idle-gap labels
+LABELS = ("run_span", "draw_batch", "stack_batches", "local_scan",
+          "event_step.1", "event_step.2", "dense_step", "skip_idle")
 
 
 def _json(path: str) -> dict:
@@ -53,6 +55,8 @@ def load_cell(name: str) -> dict:
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
+    traffic = _json(os.path.join(HERE, "traffic", cell["traffic"] + ".json"))
+    check_mesh(traffic, cell["chips"])
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     per_layer = [m for m in bench["per_layer"]
@@ -62,11 +66,24 @@ def load_cell(name: str) -> dict:
             "config_name": cell["config"],
             "config": _json(os.path.join(HERE, "configs",
                                          cell["config"] + ".json")),
-            "traffic": _json(os.path.join(HERE, "traffic",
-                                          cell["traffic"] + ".json")),
+            "traffic": traffic,
             "limits": _json(os.path.join(HERE, "limits", name + ".json")),
             "end_to_end": e2e, "per_layer": per_layer,
             "names": _json(os.path.join(HERE, "names.json"))}
+
+
+def check_mesh(traffic: dict, chips: int) -> None:
+    """A traffic's ``mesh`` [workers, data] has to cover the cell's chips
+    exactly, and its workers axis has to divide the fleet."""
+    if "mesh" not in traffic:
+        return
+    mesh = traffic["mesh"]
+    fleet = traffic["subnets"] * traffic["workers_per_subnet"]
+    if (len(mesh) != 2 or min(mesh) < 1 or math.prod(mesh) != chips
+            or fleet % mesh[0]):
+        raise SystemExit(f"chipbench: mesh {mesh} (workers, data) does not "
+                         f"fit the cell: it needs {chips} chip(s) in all and "
+                         f"a workers axis that divides the {fleet} workers")
 
 
 def reader(metric: str):
@@ -133,7 +150,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, devices: list,
 
     counter = CompileCounter()
     tc = cell_train.TrainCell(cell["config_name"], cell["config"],
-                              cell["traffic"], seed, annotate=trace)
+                              cell["traffic"], seed, devices)
     first = tc.follow(cell["traffic"]["check_slots"])
     tc.warm()
     setup_s = time.time() - START
@@ -160,7 +177,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, devices: list,
     batches = tc.batches()
     tc.free()
     ref = reference.run(tc.spec, cell["traffic"], seed, tc.gate_seed, batches,
-                        devices[0])
+                        devices)
     correct, checks, readings = compare.check(first, ref, cell["limits"])
     log(f"chipbench: readings {json.dumps(readings)}; loss gap by slot "
         f"{compare.loss_by_slot(first, ref)}", file=sys.stderr)

@@ -8,12 +8,13 @@ stat (``slots``), and device scopes (``mll.grads``, ``mll.update``,
 
 `build` adds to the benchmark's `traces.Trace` what the metrics of the
 trainer's parts need from the same file (the newest ``*.xplane.pb`` under
-`TRACE`): each device op's program and part, and the trainer's host
-spans, told by their stats (``lo``/``hi`` or ``slots``) from the
-benchmark's own spans of the same names, which carry none.  The XLA Ops
-events of a TPU v5e trace carry no ``op_name``, so an op's part comes
-from the HLO that the trace holds for its program (the "Hlo Proto" of
-the ``/host:metadata`` plane), by instruction name (`parts`).
+`TRACE`): each device op's program and part, each program's kind
+(local scan, event step, dense step: `kind_of`), and the trainer's host
+spans with their stats (``lo``/``hi`` or ``slots``).  The XLA Ops events
+of a TPU v5e trace carry no ``op_name``, so an op's part and a program's
+kind come from the HLO that the trace holds for the program (the "Hlo
+Proto" of the ``/host:metadata`` plane): a part by instruction name
+(`parts`), a kind by the scopes the program holds.
 
 An op is charged to one part.  A fusion holds the instructions XLA fused
 into it, and runs once the latest of their parts can run: it is charged
@@ -51,6 +52,9 @@ CARRY = "carry"                  # a loop's carried state, copied in and out
 RUN_SPAN = "run_span"
 LOCAL_SCAN = "local_scan"
 SKIP_IDLE = "skip_idle"
+# kinds of step program (`kind_of`), named as the spans that launch them
+EVENT_STEP = "event_step"
+DENSE_STEP = "dense_step"
 # the trainer's host spans
 HOST = re.compile(r"^(run_span|draw_batch|stack_batches|local_scan|"
                   r"event_step\.\d+|dense_step|skip_idle)$")
@@ -73,6 +77,8 @@ class Marks:
     ops: dict            # device id -> [(Op, program, part or "")]
     spans: list          # [Span] host spans that carry stats
     window: tuple        # (start, end) of the benchmark's window
+    programs: dict = dataclasses.field(default_factory=dict)
+    #                      program -> its kind (`kind_of`), or ""
 
 
 def scope_of(op_name: str) -> str:
@@ -92,14 +98,27 @@ def latest(scopes) -> str:
     return max(scopes, key=_rank, default="")
 
 
+def kind_of(instrs, rules) -> str:
+    """The kind of a step program, from the trainer scopes its
+    instructions name: the first of ``rules`` ([[kind, pattern], ...],
+    `names.json`'s ``programs``) whose pattern one of them matches; "" for
+    none.  The entry points' XLA module names do not tell them apart: a
+    dense step and a local scan both compile as ``jit__lambda``, and an
+    event step's name differs between vmap and `shard_map`."""
+    held = {scope_of(i.op_name) for i in instrs} - {""}
+    return next((kind for kind, pattern in rules
+                 if any(re.search(pattern, s) for s in held)), "")
+
+
 # --------------------------------------------------------------- reading
 def of(ctx):
     """The marks a reader reads: ``ctx.marks``, built from the run's
-    trace (``ctx.trace``) on first use; None where no device op was
-    recorded."""
+    trace (``ctx.trace``) and the program kinds of ``ctx.names`` on first
+    use; None where no device op was recorded."""
     if getattr(ctx, "marks", None) is None:
         trace = getattr(ctx, "trace", None)
-        ctx.marks = (build(trace) if trace is not None and trace.ops
+        ctx.marks = (build(trace, ctx.names["programs"])
+                     if trace is not None and trace.ops
                      else Marks({}, [], (0, 0)))
     return ctx.marks if ctx.marks.ops else None
 
@@ -114,10 +133,11 @@ def newest(directory: str) -> str:
     return paths[-1]
 
 
-def build(trace, directory: str = TRACE) -> Marks:
+def build(trace, rules, directory: str = TRACE) -> Marks:
     """The marks of ``trace`` (a `traces.Trace`): its device ops, each
-    with the program execution it starts in and its part, and the
-    trainer's host spans, read from the trace file it was loaded from."""
+    with the program execution it starts in and its part, the kind of
+    each program (by ``rules``, see `kind_of`), and the trainer's host
+    spans, read from the trace file it was loaded from."""
     path = newest(directory)
     placed = {}
     for d, ops in trace.ops.items():
@@ -130,16 +150,17 @@ def build(trace, directory: str = TRACE) -> Marks:
     with open(path, "rb") as f:
         data = f.read()
     try:
-        charged = {mod: parts(*hlo)
-                   for mod, hlo in hlo_modules(data, wanted).items()}
+        hlo = hlo_modules(data, wanted)
     except (ValueError, IndexError) as e:
         sys.stderr.write(f"chipbench: the trace's HLO could not be read "
-                         f"({e!r}); no op is charged\n")
-        charged = {}
+                         f"({e!r}); no op is charged, no program known\n")
+        hlo = {}
+    charged = {mod: parts(*h) for mod, h in hlo.items()}
     ops = {d: [(op, mod, charged.get(mod, {}).get(instruction(op.name), ""))
                for op, mod in ops]
            for d, ops in placed.items()}
-    return Marks(ops, host_spans(path), trace.window)
+    return Marks(ops, host_spans(path), trace.window,
+                 {mod: kind_of(h[1], rules) for mod, h in hlo.items()})
 
 
 def host_spans(path: str) -> list:
@@ -386,11 +407,9 @@ def part_ns(marks: Marks, part: str, programs=None) -> float:
     return total / len(marks.ops)
 
 
-def unmixed(marks: Marks) -> set:
-    """The programs with no op charged to mixing (the local scans)."""
-    every = {mod for ops in marks.ops.values() for _, mod, _ in ops}
-    return every - {mod for ops in marks.ops.values()
-                    for _, mod, p in ops if _rank(p) == ORDER.index(MIX)}
+def programs(marks: Marks, *kinds) -> set:
+    """The programs of any of ``kinds`` (`kind_of`)."""
+    return {mod for mod, k in marks.programs.items() if k in kinds}
 
 
 def _inside(marks: Marks, pattern) -> list:

@@ -29,8 +29,8 @@ def test_float8_control_fails(name):
     batches = cell_train.first_batches(spec, train, seed,
                                        train["check_slots"][-1])
     dev = jax.devices()[0]
-    ref = reference.run(spec, train, seed, seed, batches, dev)
-    ctl = reference.run(spec, train, seed, seed, batches, dev,
+    ref = reference.run(spec, train, seed, seed, batches, [dev])
+    ctl = reference.run(spec, train, seed, seed, batches, [dev],
                         lowp="float8_e4m3fn")
     ok, rows, _ = compare.check(ctl, ref, cell["limits"])
     assert not ok, rows

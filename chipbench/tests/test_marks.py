@@ -12,7 +12,7 @@ from spans import Marks, Span
 from traces import Op
 
 READERS = ("grad_slot_ms", "update_slot_ms", "mix_event_ms", "host_slot_ms",
-           "dispatch_slot_ms")
+           "dispatch_slot_ms", "collective_exposed_ms")
 
 
 LOCAL, EVENT = "jit__lambda(1)", "jit__unknown(2)"
@@ -53,7 +53,8 @@ def make():
             # a call that ends after the window does not count
             Span("run_span", 95, 120, {"lo": 9, "hi": 10}),
             Span("local_scan", 96, 110, {"slots": 1})]
-    return Marks({0: d0, 1: d1}, host, (0, 100))
+    return Marks({0: d0, 1: d1}, host, (0, 100),
+                 {LOCAL: spans.LOCAL_SCAN, EVENT: spans.EVENT_STEP})
 
 
 def test_scope_time_leaves_loop_ops_out():
@@ -63,9 +64,11 @@ def test_scope_time_leaves_loop_ops_out():
     assert spans.part_ns(m, spans.MIX) == pytest.approx((10 + 10) / 2)
     assert spans.part_ns(m, "mll.mix.hub") == pytest.approx(10 / 2)
     assert spans.part_ns(m, spans.CARRY) == pytest.approx(5 / 2)
-    # the programs that mix nothing: the local scan's update alone
-    assert spans.unmixed(m) == {LOCAL}
-    assert spans.part_ns(m, spans.UPDATE, spans.unmixed(m)) \
+    # the local scans': the update of the programs that mix nothing
+    assert spans.programs(m, spans.LOCAL_SCAN) == {LOCAL}
+    assert spans.programs(m, spans.LOCAL_SCAN, spans.EVENT_STEP) \
+        == {LOCAL, EVENT}
+    assert spans.part_ns(m, spans.UPDATE, {LOCAL}) \
         == pytest.approx((10 + 6) / 2)
 
 
@@ -99,10 +102,82 @@ def test_scope_of_an_op_name():
     assert spans.latest(set()) == ""
 
 
+def test_program_kind_from_its_scopes():
+    """A step program is told by the trainer scopes its HLO holds, by
+    the rules of `names.json`, whatever its module is named."""
+    import json
+
+    from conftest import BENCH
+    from spans import Instr
+    with open(os.path.join(BENCH, "names.json")) as f:
+        rules = json.load(f)["programs"]
+
+    def prog(*op_names):
+        return [Instr(f"i.{k}", n, k, ()) for k, n in enumerate(op_names)]
+
+    g = "jit(_lambda)/while/body/closed_call/mll.grads/dot_general"
+    u = "jit(_lambda)/while/body/closed_call/mll.update/mul"
+    assert spans.kind_of(prog(g, u, ""), rules) == spans.LOCAL_SCAN
+    for mix in ("mll.mix.subnet", "mll.mix.hub"):
+        assert spans.kind_of(prog(g, f"jit(_lambda)/shard_map/{mix}/psum",
+                                  u), rules) == spans.EVENT_STEP
+    # an all-idle event step runs the forward and the mixing alone
+    assert spans.kind_of(prog("jit(_unknown)/mll.mix.hub/add", g), rules) \
+        == spans.EVENT_STEP
+    assert spans.kind_of(prog(g, u, "jit(_lambda)/mll.mix.dense/dot"),
+                         rules) == spans.DENSE_STEP
+    # the benchmark's own programs and the feed's hold no trainer scope
+    assert spans.kind_of(prog("jit(stack)/concatenate", ""), rules) == ""
+    assert spans.kind_of([], rules) == ""
+
+
+def _collectives():
+    """Two chips' event programs, window [0, 100).  Chip 0: a fusion
+    [10, 20), an async all-reduce [15, 40) (start at 15, done ends at
+    40) of which [20, 40) runs alone, then a collective-permute [50, 60)
+    half under a fusion [55, 70).  Chip 1: the same all-reduce [15, 30),
+    under a fusion [10, 20) and a fusion [25, 35); a collective in a
+    local scan, which does not count."""
+    ar_s = "%all-reduce-start.1 = (bf16[8]{0}) all-reduce-start(bf16[8]"
+    ar_d = "%all-reduce-done.1 = bf16[8]{0} all-reduce-done((bf16[8]{0})"
+    cp = "%collective-permute.2 = bf16[8]{0} collective-permute(bf16[8]"
+    fus = "%fusion.3 = bf16[8]{0} fusion(bf16[8]{0} %a)"
+    d0 = [(Op(fus, 10, 20), EVENT, ""), (Op(ar_s, 15, 16), EVENT, ""),
+          (Op(ar_d, 39, 40), EVENT, ""), (Op(cp, 50, 60), EVENT, ""),
+          (Op(fus, 55, 70), EVENT, "")]
+    d1 = [(Op(fus, 10, 20), EVENT, ""), (Op(ar_s, 15, 16), EVENT, ""),
+          (Op(fus, 25, 35), EVENT, ""), (Op(ar_d, 29, 30), EVENT, ""),
+          (Op("%all-gather.4 = bf16[8]{0} all-gather(bf16[2]", 80, 90),
+           LOCAL, "")]
+    host = [Span("event_step.2", 0, 1, {"slots": 1, "idle": 0}),
+            Span("event_step.1", 1, 2, {"slots": 1, "idle": 0})]
+    return Marks({0: d0, 1: d1}, host, (0, 100),
+                 {LOCAL: spans.LOCAL_SCAN, EVENT: spans.EVENT_STEP})
+
+
+def test_exposed_collective_time():
+    m = _collectives()
+    # chip 0: [20, 40) and [50, 55); chip 1: [20, 25); two event slots
+    got = run.reader("collective_exposed_ms")(
+        types.SimpleNamespace(marks=m, chips=2))
+    assert got == pytest.approx((25 + 5) / 2 / 2 / 1e6)
+    # one chip: no exchange to read
+    assert run.reader("collective_exposed_ms")(
+        types.SimpleNamespace(marks=m, chips=1)) is None
+    # collectives wholly under compute read 0, not nothing
+    hidden = Marks({0: [(Op("%fusion.1 = f(%a)", 0, 50), EVENT, ""),
+                        (Op(m.ops[0][3][0].name, 10, 20), EVENT, "")]},
+                   m.spans, m.window, m.programs)
+    assert run.reader("collective_exposed_ms")(
+        types.SimpleNamespace(marks=hidden, chips=2)) == 0.0
+
+
 def test_readers():
     m = make()
-    ctx = types.SimpleNamespace(marks=m)
+    ctx = types.SimpleNamespace(marks=m, chips=2)
     got = {r: run.reader(r)(ctx) for r in READERS}
+    # the hand-made trace holds no collective: that reader finds nothing
+    assert got.pop("collective_exposed_ms") is None
     assert got == pytest.approx({
         "grad_slot_ms": 20 / 6 / 1e6, "update_slot_ms": 8 / 4 / 1e6,
         "mix_event_ms": 10 / 2 / 1e6, "host_slot_ms": 73 / 9 / 1e6,
@@ -110,12 +185,12 @@ def test_readers():
     # a trainer that names nothing: no charged op, no span with stats
     bare = Marks({0: [(op, mod, "") for op, mod, _ in m.ops[0]]}, [],
                  (0, 100))
-    ctx = types.SimpleNamespace(marks=bare)
+    ctx = types.SimpleNamespace(marks=bare, chips=2)
     assert all(run.reader(r)(ctx) is None for r in READERS)
     # no device op recorded at all, or no trace
-    ctx = types.SimpleNamespace(marks=Marks({}, m.spans, (0, 100)))
+    ctx = types.SimpleNamespace(marks=Marks({}, m.spans, (0, 100)), chips=2)
     assert all(run.reader(r)(ctx) is None for r in READERS)
-    assert all(run.reader(r)(types.SimpleNamespace()) is None
+    assert all(run.reader(r)(types.SimpleNamespace(chips=2)) is None
                for r in READERS)
 
 

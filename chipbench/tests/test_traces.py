@@ -1,4 +1,6 @@
 """The trace reductions, on a hand-made trace."""
+import dataclasses
+
 import pytest
 
 import traces
@@ -39,9 +41,20 @@ def test_idle_share():
 
 def test_program_and_kernel_time():
     tr = make()
-    assert traces.module_ns(tr, r"^jit__lambda\(") == 30 + 50
-    assert traces.module_ns(tr, r"^jit__unknown\(") == 30 + 30
+    assert traces.module_ns(tr, {"jit__lambda(123)"}) == 30 + 50
+    assert traces.module_ns(tr, {"jit__unknown(9)"}) == 30 + 30
+    assert traces.module_ns(tr, {"jit__lambda(123)", "jit__unknown(9)"}) \
+        == 30 + 50 + 30 + 30
+    assert traces.module_ns(tr, set()) == 0
     assert traces.op_ns(tr, r"\) custom-call\(") == 20
+
+
+def test_interval_difference():
+    assert traces.minus([(0, 10), (20, 30)], [(2, 4), (8, 22), (25, 26)]) \
+        == [(0, 2), (4, 8), (22, 25), (26, 30)]
+    assert traces.minus([(0, 10)], []) == [(0, 10)]
+    assert traces.minus([(0, 10)], [(-5, 15)]) == []
+    assert traces.minus([(5, 6)], [(0, 1), (7, 9)]) == [(5, 6)]
 
 
 def test_name_table_tells_the_kernels_apart():
@@ -80,13 +93,15 @@ def test_breakdown():
 
 def test_readers():
     """Each per-layer reader on the hand-made trace: two chips, each with
-    its `local_scan` and `event_step` executions."""
+    its `local_scan` and `event_step` executions, the programs' kinds as
+    their scopes give them."""
     import json
     import os
     import types
 
     import flops
     import run
+    import spans
     from conftest import BENCH, PEAKS, tiny_cell
     from weights import Spec
     with open(os.path.join(BENCH, "names.json")) as f:
@@ -95,10 +110,15 @@ def test_readers():
     spec = Spec.from_json(cell["config"])
     win = {"seconds": 1.0, "slots": 3, "local_slots": 2, "event_slots": 1,
            "tokens": 3 * 4 * 32}
+    tr = make()
+    marks = spans.Marks({d: [(o, "", "") for o in ops]
+                         for d, ops in tr.ops.items()}, [], tr.window,
+                        {"jit__lambda(123)": spans.LOCAL_SCAN,
+                         "jit__unknown(9)": spans.EVENT_STEP})
     ctx = types.SimpleNamespace(
-        trace=make(), traces=traces, flops=flops, spec=spec,
+        trace=tr, traces=traces, flops=flops, spec=spec,
         traffic=cell["traffic"], window=win, chips=2, peaks=PEAKS,
-        names=names)
+        names=names, marks=marks)
     got = {m: run.reader(m)(ctx) for m in (
         "train_mfu", "local_slot_ms", "event_slot_ms",
         "device_idle_share.train", "flash_fwd_roofline")}
@@ -112,6 +132,12 @@ def test_readers():
     assert got["flash_fwd_roofline"] == pytest.approx(
         100 * flops.attention_least_seconds(spec, cell["traffic"], 3, PEAKS,
                                             False) / 20e-9)
-    empty = types.SimpleNamespace(**dict(vars(ctx), trace=Trace({}, {}, [],
-                                                                (0, 100))))
+    # the same programs of unknown kind: no program time is read
+    blind = dataclasses.replace(marks, programs=dict.fromkeys(marks.programs,
+                                                              ""))
+    unknown = types.SimpleNamespace(**dict(vars(ctx), marks=blind))
+    assert all(run.reader(m)(unknown) is None
+               for m in ("train_mfu", "local_slot_ms", "event_slot_ms"))
+    empty = types.SimpleNamespace(**dict(
+        vars(ctx), trace=Trace({}, {}, [], (0, 100)), marks=None))
     assert all(run.reader(m)(empty) is None for m in got)

@@ -103,6 +103,24 @@ def length(intervals) -> float:
     return sum(e - s for s, e in intervals)
 
 
+def minus(a, b) -> list:
+    """The parts of disjoint sorted intervals ``a`` that no interval of
+    disjoint sorted ``b`` covers."""
+    out, k = [], 0
+    for s, e in a:
+        while k < len(b) and b[k][1] <= s:
+            k += 1
+        j = k
+        while j < len(b) and b[j][0] < e:
+            if b[j][0] > s:
+                out.append((s, b[j][0]))
+            s = max(s, b[j][1])
+            j += 1
+        if s < e:
+            out.append((s, e))
+    return out
+
+
 def busy_ns(trace: Trace) -> dict:
     """Per device, the union of its op intervals inside the window."""
     lo, hi = trace.window
@@ -116,26 +134,27 @@ def idle_share(trace: Trace) -> float:
     return 1.0 - sum(busy.values()) / len(busy) / trace.window_ns
 
 
-def _inside(trace: Trace, table: dict, pattern: str):
-    rx = re.compile(pattern)
+def _inside(trace: Trace, table: dict, match):
     lo, hi = trace.window
     for ops in table.values():
         for o in ops:
-            if o.end > lo and o.start < hi and rx.search(o.name):
+            if o.end > lo and o.start < hi and match(o.name):
                 yield o
 
 
-def module_ns(trace: Trace, pattern: str) -> float:
-    """Device time of the executions of every program whose name matches,
-    summed over devices (an execution overlapping the window counts
-    whole)."""
-    return sum(o.end - o.start for o in _inside(trace, trace.modules, pattern))
+def module_ns(trace: Trace, programs) -> float:
+    """Device time of the executions of the programs named in
+    ``programs``, summed over devices (an execution overlapping the window
+    counts whole)."""
+    return sum(o.end - o.start for o in
+               _inside(trace, trace.modules, programs.__contains__))
 
 
 def op_ns(trace: Trace, pattern: str) -> float:
     """Device time of the ops whose instruction text matches, summed over
     devices."""
-    return sum(o.end - o.start for o in _inside(trace, trace.ops, pattern))
+    rx = re.compile(pattern)
+    return sum(o.end - o.start for o in _inside(trace, trace.ops, rx.search))
 
 
 def top_ops(trace: Trace, n: int = 10) -> list:
@@ -143,7 +162,7 @@ def top_ops(trace: Trace, n: int = 10) -> list:
     numeric suffix (loop ops left out), averaged over devices:
     [[name, seconds], ...]."""
     agg = {}
-    for o in _inside(trace, trace.ops, ""):
+    for o in _inside(trace, trace.ops, lambda name: True):
         if o.kind not in LOOPS:
             agg[o.kind] = agg.get(o.kind, 0.0) + (o.end - o.start)
     k = max(len(trace.ops), 1)
